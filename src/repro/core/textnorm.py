@@ -42,6 +42,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -149,18 +150,17 @@ FW_KEYS = _build_fw_keys()                 # sorted unique, host membership
 FW_KEY_SET = frozenset(int(k) for k in FW_KEYS)
 
 
-def _pad_pow2(keys: np.ndarray, lane: int = 128) -> np.ndarray:
-    rp = lane
-    while rp < keys.shape[0]:
-        rp *= 2
+def _fw_rows(keys: np.ndarray, lane: int = 128) -> np.ndarray:
+    rp = -(-keys.shape[0] // lane) * lane
     return np.pad(keys, (0, rp - keys.shape[0]),
-                  constant_values=FW_SENTINEL)
+                  constant_values=FW_SENTINEL).reshape(-1, lane)
 
 
-# sorted + sentinel-padded to a pow2 >= one lane row: the same layout
-# stem_match.pad_dict_sorted gives root dictionaries, so the kernel ships
-# it to VMEM as a (rows, 128) tile and bsearch_hit runs unchanged on it
-FW_FLAT = _pad_pow2(FW_KEYS)
+# sentinel-padded to whole 128-lane rows: the exemption check compares a
+# word's packed key against every entry (a few dozen keys, so the
+# all-pairs compare is one vreg row per word tile, in XLA and in the
+# kernel alike)
+FW_ROWS = _fw_rows(FW_KEYS)
 
 
 # ---------------------------------------------------------------------------
@@ -291,28 +291,35 @@ def classify_codes(chars, lut):
                      CLS_SEP)
 
 
-def strip_and_pack(codes, lens, fw_flat):
+def _iota_row(n: int):
+    """int32[1, n] 0..n-1 (2-D: Mosaic has no 1-D iota)."""
+    return jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+
+
+def strip_and_pack(codes, lens, fw_rows):
     """Normalised letter rows -> stripped, packed word-tile rows.
 
     codes int32[n, CMAX]  left-aligned letter codes, 0 beyond ``lens``
     lens  int32[n]        letters per row (<= CMAX)
-    fw_flat int32[Fp]     FW_FLAT (sorted, sentinel-padded pow2)
+    fw_rows int32[r, 128] FW_ROWS (function-word keys, sentinel-padded)
     -> int32[n, 16]
 
-    Branchless: function-word exemption via bsearch_hit on the packed
-    5-letter key; proclitic as a first-match scan over the pattern list
-    (longest first); enclitic chars located by one-hot sums at absolute
-    position lens - L + k (no gather along traced offsets); the
-    proclitic shift realised as a select over the 4 static shifts.
+    Branchless and gather-free, so it lowers inside a TPU kernel:
+    function-word exemption by comparing the packed 5-letter key against
+    every FW_ROWS entry; proclitic as a first-match scan over the
+    pattern list (longest first); enclitic chars located by one-hot sums
+    at absolute position lens - L + k; the proclitic shift realised as a
+    select over the 4 static shifts.
     """
-    from repro.kernels import stem_match as sm  # lazy: core -> kernels
-
     codes = codes.astype(jnp.int32)
     lens = lens.astype(jnp.int32)
     n, cm = codes.shape
     key5 = ((((codes[:, 0] * 64 + codes[:, 1]) * 64 + codes[:, 2]) * 64
              + codes[:, 3]) * 64 + codes[:, 4])
-    exempt = (lens <= FW_MAXLEN) & sm.bsearch_hit(fw_flat, key5)
+    listed = jnp.zeros((n,), bool)
+    for r in range(fw_rows.shape[0]):
+        listed |= jnp.any(key5[:, None] == fw_rows[r:r + 1, :], axis=1)
+    exempt = (lens <= FW_MAXLEN) & listed
 
     pro = jnp.zeros((n,), jnp.int32)
     found = exempt
@@ -325,7 +332,7 @@ def strip_and_pack(codes, lens, fw_flat):
         found |= m
 
     rem_len = lens - pro
-    j = jnp.arange(cm, dtype=jnp.int32)[None, :]
+    j = _iota_row(cm)
 
     def char_at(pos):   # codes[i, pos[i]] without a gather (one-hot sum)
         return jnp.sum(jnp.where(j == pos[:, None], codes, 0), axis=1)
@@ -349,8 +356,21 @@ def strip_and_pack(codes, lens, fw_flat):
     for p in sorted({len(pat) for pat in PROCLITIC_CODES} | {0}):
         shifted = jnp.where((pro == p)[:, None],
                             codes[:, p:p + ab.MAXLEN], shifted)
-    keep = jnp.arange(ab.MAXLEN, dtype=jnp.int32)[None, :] < out_len[:, None]
+    keep = _iota_row(ab.MAXLEN) < out_len[:, None]
     return jnp.where(keep, shifted, 0)
+
+
+def class_windows(chars, starts, lens):
+    """Per-word class windows: int32[T] codepoints + word starts/lengths
+    int32[W] -> int32[W, MAX_RAW], the class of each word's first MAX_RAW
+    raw codepoints, CLS_SEP past the word's end. The irregular
+    per-word read of the codepoint tile, done as one XLA gather ahead of
+    the front-end kernel."""
+    cls = classify_codes(chars, jnp.asarray(CLASS_LUT))
+    idx = starts[:, None] + _iota_row(MAX_RAW)
+    win = jnp.take(cls, jnp.clip(idx, 0, chars.shape[0] - 1), mode="clip")
+    live = _iota_row(MAX_RAW) < jnp.minimum(lens, MAX_RAW)[:, None]
+    return jnp.where(live, win, CLS_SEP)
 
 
 # ---------------------------------------------------------------------------
@@ -444,5 +464,5 @@ def frontend_reference(chars, *, block_w: int = 128,
     grid = jnp.zeros((wp, CMAX), jnp.int32).at[rows, pos].set(
         cls, mode="drop")
     nlet = jnp.zeros(wp, jnp.int32).at[rows].add(1, mode="drop")
-    words = strip_and_pack(grid, nlet, jnp.asarray(FW_FLAT))
+    words = strip_and_pack(grid, nlet, jnp.asarray(FW_ROWS))
     return words, geo

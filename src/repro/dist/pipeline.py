@@ -24,7 +24,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import stemmer
@@ -85,8 +84,8 @@ def pipeline_map(stage_fns, bundle, mesh, axis: str = "stage"):
                 jnp.where(idx == s_count - 1, x, jnp.zeros_like(x)), axis),
             outs)
 
-    f = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                  check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                      check_vma=False)
     return f(bundle)
 
 

@@ -22,7 +22,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import stemmer as core_stemmer
@@ -82,8 +81,8 @@ def _shard_call(words, roots, *, mesh, axis, infix, match, block_b,
             num_buffers=num_buffers, skip_index=skip_index,
             visit_budget=visit_budget, interpret=interpret)
 
-    f = shard_map(local, mesh=mesh, in_specs=(P(axis), P()),
-                  out_specs=(P(axis), P(axis)), check_rep=False)
+    f = jax.shard_map(local, mesh=mesh, in_specs=(P(axis), P()),
+                      out_specs=(P(axis), P(axis)), check_vma=False)
     root, source = f(wp, roots)
     root, source = root[:b], source[:b]
     if with_checksum:

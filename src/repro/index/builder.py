@@ -217,7 +217,7 @@ def _write_partial(ckpt_dir: str, i: int, part: IndexPartial,
 # the driver
 # ---------------------------------------------------------------------------
 def build_corpus_index(stream, roots, *, mesh=None, checkpoint_dir=None,
-                       resume: bool = False, block_b: int = 2048,
+                       resume: bool = False, block_b: int = 256,
                        block_w: int = 2048, interpret: bool | None = None,
                        injector=None, chunk_retries: int = 2,
                        **stem_kw) -> RootIndex:

@@ -407,7 +407,6 @@ def _index_sharded_jit(words, roots, vocab, doc_ids, positions, *, mesh,
                        axis, infix, match, block_b, residency, dict_block_r,
                        num_buffers, skip_index, visit_budget, block_w,
                        interpret):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.dist import mesh_axis_size
@@ -431,8 +430,8 @@ def _index_sharded_jit(words, roots, vocab, doc_ids, positions, *, mesh,
                                         block_w=block_w, interpret=interpret)
         return hist, rank, ids
 
-    f = shard_map(local, mesh=mesh, in_specs=(P(axis), P(), P()),
-                  out_specs=(P(axis), P(axis), P(axis)), check_rep=False)
+    f = jax.shard_map(local, mesh=mesh, in_specs=(P(axis), P(), P()),
+                      out_specs=(P(axis), P(axis), P(axis)), check_vma=False)
     hist, rank, ids = f(wp, roots, vocab)
     # the device-side shard merge: corpus shards are contiguous slices,
     # so stacking per-shard tile histograms restores corpus tile order
@@ -443,7 +442,7 @@ def _index_sharded_jit(words, roots, vocab, doc_ids, positions, *, mesh,
 
 def build_root_index(words, roots, vocab, doc_ids, positions, *,
                      mesh=None, axis: str = "data", infix: bool = True,
-                     match: str = "bsearch", block_b: int = 2048,
+                     match: str = "bsearch", block_b: int = 256,
                      residency: str = "auto", dict_block_r: int = 8,
                      num_buffers: int = 2, skip_index: bool = True,
                      visit_budget: int | None = None, block_w: int = 2048,

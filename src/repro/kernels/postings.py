@@ -1,28 +1,32 @@
 """Pallas TPU kernel: the inverted-index postings reduction.
 
 The corpus indexer (repro.index) needs root -> (doc, position) postings
-for millions of words without a host loop. The classic device recipe is
-sort + segment-reduce + scatter, and this kernel runs the per-tile half
-of it on the accelerator:
+for millions of words without a host loop. The device recipe is a
+per-tile count + a global scan + a scatter, and this kernel runs the
+per-tile half on the accelerator. Each grid step takes one
+``block_w``-word tile of root ids, laid out lane-dense as
+``(block_w / 128, 128)`` rows (word ``i`` at row ``i // 128``, lane
+``i % 128``), and emits
 
-  * each grid step takes one ``block_w``-word tile of root ids and sorts
-    the composite keys ``id * block_w + lane`` with an in-register
-    bitonic network (block_w is a power of two, so the network is a
-    static ``log2^2`` cascade of predicated compare-exchanges — no data-
-    dependent control flow, same discipline as ``stem_match.bsearch_hit``);
-  * bucket boundaries then fall out of a branchless lower-bound search:
-    ``log2(block_w)`` bisection steps per query give the per-tile root
-    histogram (segment reduce) and, re-run at each word's own composite
-    key, its stable rank within its root segment.
+  * the tile's root histogram — for each 128-root row of the id space,
+    one ``[128 words, 128 roots]`` compare per 128-word chunk, summed
+    over words (a segment reduce without a sort);
+  * each word's stable rank within its root in the tile — the number of
+    earlier words of the tile with the same id, one ``[128, 128]``
+    compare per (word chunk, earlier chunk) pair.
 
-Histograms and ranks are tiny next to the word stream, so the global
-side of the reduction — exclusive cumsums over (tile, root) and the
-final scatter of (doc, position) pairs into the postings array — runs as
-XLA ops in the same jit scope (:func:`finish_postings`), exactly the
-PR 5/PR 7 visit-index pattern: scatters in XLA, dense per-word work in
-the kernel. Composite keys make the sort stable in (tile, lane) order,
-so postings within a root come out sorted by global word index with no
-tie-breaking pass.
+(Tiles narrower than 128 words, a CPU-test size, use one row of
+``block_w`` lanes.)
+
+Every step is a static compare-and-sum on whole (8, 128) vreg tiles: no
+gather, no data-dependent control flow. Histograms and ranks are tiny
+next to the word stream, so the global side of the reduction —
+exclusive cumsums over (tile, root) and the final scatter of (doc,
+position) pairs into the postings array — runs as XLA ops in the same
+jit scope (:func:`finish_postings`), exactly the PR 5/PR 7 visit-index
+pattern: scatters in XLA, dense per-word work in the kernel. Ranks count
+earlier words in (tile, lane) order, so postings within a root come out
+sorted by global word index with no tie-breaking pass.
 
 Invalid words (no root found, padding) are assigned the drop bucket
 ``id == n_roots``; their scatter destinations land out of bounds and
@@ -36,76 +40,51 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.stem_match import _ceil_log2
-
 LANE = 128
+SUBLANE = 8
 
-# int32 composite keys: id * block_w + lane must not overflow.
-MAX_COMPOSITE = 1 << 31
-
-
-def _iota(n: int) -> jnp.ndarray:
-    """int32[n] 0..n-1 (2D broadcasted_iota — TPU has no 1D iota)."""
-    return jax.lax.broadcasted_iota(jnp.int32, (1, n), 1).reshape(n)
+# the per-tile histogram block is (n_roots + 1) int32, double-buffered
+# in VMEM next to the word tile; past this the id space is too large
+HIST_VMEM_BYTES = 4 << 20
 
 
-def _bitonic_sort(keys: jnp.ndarray) -> jnp.ndarray:
-    """Ascending bitonic sort of int32[n], n a power of two.
+def _iota(shape, dim: int) -> jnp.ndarray:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
 
-    Fully static network: log2(n)*(log2(n)+1)/2 vectorised compare-
-    exchange stages, each a gather at the lane's partner (``lane ^ j``)
-    plus a predicated min/max select — branchless, like the bsearch.
+
+def _postings_kernel(ids_ref, hist_ref, rank_ref):
+    """Grid (n_tiles,): one word tile -> (root histogram, in-root rank).
+
+    ids (nr, width) -> hist (hist_rows, LANE) with root ``h * LANE + l``
+    at [h, l], and rank (nr, width) in the ids' layout.
     """
-    n = keys.shape[0]
-    lane = _iota(n)
-    for k in (1 << s for s in range(1, _ceil_log2(n) + 1)):
-        j = k // 2
-        while j:
-            partner = jnp.take(keys, lane ^ j, mode="clip")
-            up = (lane & k) == 0          # ascending run?
-            low = (lane & j) == 0         # lower end of the exchange?
-            keys = jnp.where(up == low, jnp.minimum(keys, partner),
-                             jnp.maximum(keys, partner))
-            j //= 2
-    return keys
+    ids = ids_ref[...]
+    nr, width = ids.shape
+    t = ids.T                                         # (width, nr)
+    cols = [t[:, a:a + 1] for a in range(nr)]         # word chunk a, (width, 1)
+    sub = _iota((width, width), 0)
+    lane = _iota((width, width), 1)
 
+    ranks = []
+    for a in range(nr):
+        def earlier(c, acc, a=a):
+            eq = ((cols[a] == ids_ref[pl.ds(c, 1), :])
+                  & (c * width + lane < a * width + sub))
+            return acc + jnp.sum(eq.astype(jnp.int32), axis=1, keepdims=True)
+        ranks.append(jax.lax.fori_loop(0, a + 1, earlier,
+                                       jnp.zeros((width, 1), jnp.int32)))
+    rank_ref[...] = jnp.concatenate(ranks, axis=1).T
 
-def _lower_bound(sorted_keys: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
-    """Count of elements in sorted int32[n] (n pow2) strictly below q.
+    def hist_row(h, carry):
+        roots = h * LANE + _iota((1, LANE), 1)
+        n = jnp.zeros((1, LANE), jnp.int32)
+        for col in cols:
+            n = n + jnp.sum((col == roots).astype(jnp.int32), axis=0,
+                            keepdims=True)
+        hist_ref[pl.ds(h, 1), :] = n
+        return carry
 
-    Branchless: ceil(log2 n) predicated bisection steps (the
-    ``bsearch_hit`` discipline), then one final adjust for the
-    everything-smaller case.
-    """
-    n = sorted_keys.shape[0]
-    lo = jnp.zeros(q.shape, jnp.int32)
-    hi = jnp.full(q.shape, n - 1, jnp.int32)
-    for _ in range(_ceil_log2(n)):
-        mid = (lo + hi) // 2
-        v = jnp.take(sorted_keys, mid, mode="clip")
-        ge = v >= q
-        hi = jnp.where(ge, mid, hi)
-        lo = jnp.where(ge, lo, mid + 1)
-    return lo + (jnp.take(sorted_keys, lo, mode="clip") < q)
-
-
-def _postings_kernel(ids_ref, hist_ref, rank_ref, *, block_w, n_roots_pad):
-    """Grid (n_tiles,): one word tile -> (root histogram, in-segment rank).
-
-    Composite keys ``id * block_w + lane`` are unique, so the bitonic
-    sort needs no stability of its own and the rank of word ``lane`` is
-    simply its key's position minus its root segment's start.
-    """
-    ids = ids_ref[0, :]                                     # (block_w,)
-    lane = _iota(block_w)
-    keys = ids * block_w + lane
-    skeys = _bitonic_sort(keys)
-    # segment boundaries at every bucket start r * block_w (one extra
-    # query closes the last bucket)
-    bounds = _lower_bound(skeys, _iota(n_roots_pad + 1) * block_w)
-    hist_ref[0, :] = (bounds[1:] - bounds[:-1]).astype(jnp.int32)
-    seg_start = jnp.take(bounds, ids, mode="clip")
-    rank_ref[0, :] = _lower_bound(skeys, keys) - seg_start
+    jax.lax.fori_loop(0, hist_ref.shape[0], hist_row, 0)
 
 
 @functools.partial(jax.jit,
@@ -118,35 +97,43 @@ def postings_pallas(ids: jnp.ndarray, *, n_roots: int, block_w: int = 2048,
       hist int32[n_tiles, n_roots + 1]  per-tile root histogram
       rank int32[W_pad]                 stable rank within (tile, root)
 
-    W pads up to a ``block_w`` multiple with drop-bucket ids. One
+    W pads up to a ``block_w`` multiple with drop-bucket ids; block_w is
+    a power of two (a multiple of 1024 on a TPU, so a tile's
+    ``(block_w / 128, 128)`` rows cover whole (8, 128) vreg tiles). One
     pallas_call, grid over word tiles; combine across tiles (and shards)
     with :func:`finish_postings`.
     """
     if block_w & (block_w - 1):
         raise ValueError(f"block_w must be a power of two, got {block_w}")
+    if not interpret and block_w % (SUBLANE * LANE):
+        raise ValueError(f"block_w={block_w} must be a multiple of"
+                         f" {SUBLANE * LANE} on a TPU")
     n_roots_pad = n_roots + 1                  # +1: the drop bucket
-    if n_roots_pad * block_w >= MAX_COMPOSITE:
+    hist_rows = -(-n_roots_pad // (SUBLANE * LANE)) * SUBLANE
+    if 2 * hist_rows * LANE * 4 > HIST_VMEM_BYTES:
         raise ValueError(
-            f"composite sort keys overflow int32: ({n_roots} roots + drop)"
-            f" * block_w {block_w} >= 2^31 — lower block_w")
+            f"per-tile histogram of {n_roots} roots overflows the"
+            f" {HIST_VMEM_BYTES >> 20} MiB VMEM budget")
     w = ids.shape[0]
     pad = (-w) % block_w
+    width = min(block_w, LANE)
+    nr = block_w // width
     ids_p = jnp.pad(ids.astype(jnp.int32), (0, pad),
-                    constant_values=n_roots).reshape(-1, block_w)
-    n_tiles = ids_p.shape[0]
+                    constant_values=n_roots).reshape(-1, width)
+    n_tiles = ids_p.shape[0] // nr
     hist, rank = pl.pallas_call(
-        functools.partial(_postings_kernel, block_w=block_w,
-                          n_roots_pad=n_roots_pad),
+        _postings_kernel,
         grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((1, block_w), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, n_roots_pad), lambda i: (i, 0)),
-                   pl.BlockSpec((1, block_w), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((nr, width), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((hist_rows, LANE), lambda i: (i, 0)),
+                   pl.BlockSpec((nr, width), lambda i: (i, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, n_roots_pad), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, block_w), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles * hist_rows, LANE), jnp.int32),
+            jax.ShapeDtypeStruct(ids_p.shape, jnp.int32),
         ],
         interpret=interpret,
     )(ids_p)
+    hist = hist.reshape(n_tiles, hist_rows * LANE)[:, :n_roots_pad]
     return hist, rank.reshape(-1)
 
 
@@ -182,7 +169,7 @@ def finish_postings(hist, rank, ids, doc_ids, positions, *, n_roots: int,
     offsets = jnp.cumsum(counts) - counts                # exclusive
     n_postings = counts.sum()
 
-    tile_of = _iota(w) // block_w
+    tile_of = jnp.arange(w, dtype=jnp.int32) // block_w
     safe_ids = jnp.minimum(ids, n_roots)                 # gather-safe
     base = (jnp.take(jnp.concatenate([offsets, n_postings[None]]), safe_ids,
                      mode="clip")

@@ -10,23 +10,29 @@ enters VMEM once and ``(root, source)`` comes out — candidates, validity
 flags and hit masks live only in registers/VMEM.
 
 Layout (see DESIGN.md §5):
-  - the three packed root dictionaries (tri/quad/bi, int32 keys; ~2K
-    entries total for realistic dictionaries) ride along as
-    VMEM-resident blocks with a constant index map, so the pipeline
-    fetches them once and revisits them for every batch tile;
+  - word tiles travel lane-dense as ``[16, block_b]`` (word index along
+    lanes) and are transposed in VMEM; the output is one ``[8, block_b]``
+    tile per word tile (root char codes in rows 0-3, source in row 4)
+    — DESIGN.md §5.5 has why;
+  - the three packed root dictionaries (tri/quad/bi, int32 keys; 5.5K
+    entries for a general dictionary) ride along as VMEM-resident
+    ``(rows, 128)`` blocks of sorted keys with a constant index map, so
+    the pipeline fetches them once and revisits them for every batch
+    tile;
   - stages 1-4 are the shared :func:`stem_datapath.candidate_columns`
     datapath (unrolled AND/OR masking networks, truncation grid, infix
     transforms, 24-bit key packing);
   - stage 5 (Compare) supports two in-kernel strategies:
-      match="bank"     all-pairs equality against the dictionary tile —
-                       the paper's comparator banks (O(R) per candidate);
-      match="bsearch"  unrolled branchless binary search over the sorted
-                       dictionary — the paper's §7 proposed tree search
-                       (ceil(log2 R) static steps, O(log R) per
-                       candidate); see stem_match.bsearch_hit;
-  - the priority select (first hit in VHDL candidate order) is a
-    cumulative-sum one-hot reduction, so no gather is needed on the
-    output side.
+      match="bank"     all-pairs equality against the dictionary, one
+                       128-key row at a time — the paper's comparator
+                       banks (O(R) per candidate);
+      match="bsearch"  two-level sorted search — the paper's §7 proposed
+                       tree search: a row-max count picks the key's row,
+                       a one-hot MXU matmul fetches it, 128 compares
+                       finish (stem_match.sorted_member);
+  - the priority select (first hit in VHDL candidate order) is an
+    unrolled first-hit scan over the candidate columns, so no gather is
+    needed on the output side.
 
 Dictionaries large enough to pressure VMEM (>~64K keys) take the
 *streamed* Compare path (DESIGN.md §5.3), an explicitly pipelined sweep:
@@ -42,7 +48,7 @@ Dictionaries large enough to pressure VMEM (>~64K keys) take the
   - the dictionary stream stays in HBM (``memory_space=ANY``) and the
     kernel drives its own multi-buffered ``pltpu.make_async_copy``
     ladder (``num_buffers`` deep): the DMA for visit k+num_buffers-1 is
-    started before visit k's bsearch/bank compare runs, replacing the
+    started before visit k's Compare runs, replacing the
     implicit single-stage Pallas pipeline of the previous layout. An
     OR-accumulating hit mask persists in VMEM scratch across the sweep;
     the priority select runs once per batch tile after it.
@@ -86,6 +92,10 @@ GROUP_TAGS = (
 MAX_RESIDENT_KEYS = 1 << 16
 RESIDENCIES = ("resident", "streamed", "auto")
 MAX_NUM_BUFFERS = 4
+# rows of the transposed output tile: root char codes 0-3, source, 3 pad
+OUT_ROWS = 8
+# a resident dictionary ships as whole (8, 128) tiles (sm.pad_dict_tiles)
+RESIDENT_TILE_ROWS = 8
 _KEY_NOWHERE = jnp.iinfo(jnp.int32).min  # lands in no tile: below every min
 # Scalar-prefetch budget for the streamed tile-visit table, in int32
 # entries (the table is [batch_tiles, n_dict_tiles]). A megabatch whose
@@ -121,59 +131,67 @@ def choose_residency(roots, residency: str = "auto", *,
             else "resident")
 
 
-def _bank_hit(flat_dict: jnp.ndarray, keys: jnp.ndarray) -> jnp.ndarray:
-    """All-pairs comparator bank: keys[bb,6] vs flat_dict[Rp] -> bool[bb,6]."""
-    return (keys[..., None] == flat_dict[None, None, :]).any(-1)
+def _candidates(words_t, n_groups: int):
+    """Stages 1-4 on one transposed word tile int32[16, bb] -> (keys,
+    valid): lists of n_slots int32 / bool ``[bb, 1]`` columns.
 
-
-def _priority_select(keys, hits_i, root_ref, src_ref, *, n_groups: int):
-    """Stage 5b: first hit in VHDL candidate order -> (root, source) tiles.
-
-    One-hot of the first True per row — cumsum==1 on a hit slot — so the
-    winning key/tag fall out of a masked sum, gather-free.
+    Word tiles travel lane-dense as ``[16, bb]`` (a ``[bb, 16]`` slice
+    would be a 16-of-128-lane DMA the TPU refuses); the in-kernel
+    transpose hands the shared datapath its usual ``[bb, 16]`` tile.
     """
-    is_first = hits_i * (jnp.cumsum(hits_i, axis=1) == 1)
-    chosen = (keys * is_first).sum(axis=1)             # 0 when no hit
-    # per-group tag weights are static python ints (no captured constants)
-    grp_first = is_first.reshape(-1, n_groups, N_CAND).sum(axis=2)
-    source = sum(int(GROUP_TAGS[g]) * grp_first[:, g] for g in range(n_groups))
-    root_ref[...] = jnp.stack(
-        [(chosen >> 18) & 63, (chosen >> 12) & 63,
-         (chosen >> 6) & 63, chosen & 63], axis=1)
-    src_ref[...] = source[:, None]
-
-
-def _candidates(w, n_groups: int):
-    """Stages 1-4 on one word tile -> (keys[bb, n_slots], valid[bb, n_slots])."""
-    key_cols, val_cols = sdp.candidate_columns(w)
+    key_cols, val_cols = sdp.candidate_columns(words_t.T)
     n_slots = n_groups * N_CAND
-    keys = jnp.stack(key_cols[:n_slots], axis=1)
-    valid = jnp.stack(val_cols[:n_slots], axis=1) > 0
-    return keys, valid
+    return ([k[:, None] for k in key_cols[:n_slots]],
+            [v[:, None] > 0 for v in val_cols[:n_slots]])
 
 
-def _resident_hits(keys, valid, dicts, *, n_groups: int, match: str):
-    """Stage 5a against VMEM-resident dictionaries -> bool[bb, n_slots]."""
-    hit_cols = []
-    for g in range(n_groups):
-        kg = keys[:, g * N_CAND : (g + 1) * N_CAND]
-        d = dicts[GROUP_DICTS[g]]
-        hit_cols.append(sm.bsearch_hit(d, kg) if match == "bsearch"
-                        else _bank_hit(d, kg))
-    return jnp.concatenate(hit_cols, axis=1) & valid
+def _membership(rows_ref, match: str):
+    """Stage 5a strategy over one sorted (rows, LANE) dictionary ref ->
+    ``keys int32[bb, 1] -> bool[bb, 1]``. The bsearch row tables are
+    built once here and reused for every candidate column."""
+    if match == "bsearch":
+        return functools.partial(sm.sorted_member,
+                                 sm.sorted_tables(rows_ref[...]))
+    return functools.partial(sm.bank_rows_member, rows_ref)
 
 
-def _fused_kernel(words_ref, tri_ref, quad_ref, bi_ref, root_ref, src_ref,
-                  *, n_groups: int, match: str):
+def _priority_select(keys, hits):
+    """Stage 5b: first hit in VHDL candidate order -> the transposed
+    output tile int32[8, bb]: rows 0-3 the root's char codes, row 4 the
+    source tag, rows 5-7 zero. An unrolled first-hit scan over the
+    candidate columns — no cumsum, no gather."""
+    chosen = jnp.zeros_like(keys[0])
+    source = jnp.zeros_like(keys[0])           # SRC_NONE when no hit
+    found = jnp.zeros(keys[0].shape, bool)
+    for s, (k, h) in enumerate(zip(keys, hits)):
+        take = h & ~found
+        chosen = jnp.where(take, k, chosen)
+        source = jnp.where(take, int(GROUP_TAGS[s // N_CAND]), source)
+        found = found | h
+    zero = jnp.zeros_like(chosen)
+    return jnp.concatenate(
+        [(chosen >> 18) & 63, (chosen >> 12) & 63, (chosen >> 6) & 63,
+         chosen & 63, source, zero, zero, zero], axis=1).T
+
+
+def _resident_hits(keys, valid, dict_refs, *, match: str):
+    """Stage 5a against VMEM-resident dictionaries -> bool [bb, 1] per
+    candidate slot."""
+    n_groups = len(keys) // N_CAND
+    # dict.fromkeys, not set: a fixed trace order keeps the kernel (and
+    # its persistent-cache key) identical across processes
+    member = {name: _membership(dict_refs[name], match)
+              for name in dict.fromkeys(GROUP_DICTS[:n_groups])}
+    return [member[GROUP_DICTS[s // N_CAND]](k) & v
+            for s, (k, v) in enumerate(zip(keys, valid))]
+
+
+def _fused_kernel(words_ref, tri_ref, quad_ref, bi_ref, out_ref, *,
+                  n_groups: int, match: str):
     keys, valid = _candidates(words_ref[...], n_groups)  # stages 1-4
-    dicts = {"tri": tri_ref[...].reshape(-1),
-             "quad": quad_ref[...].reshape(-1),
-             "bi": bi_ref[...].reshape(-1)}
-    # ---- stage 5a: Compare — per-group match against the resident dict ---
-    hits = _resident_hits(keys, valid, dicts, n_groups=n_groups, match=match)
-    # ---- stage 5b ----
-    _priority_select(keys, hits.astype(jnp.int32), root_ref, src_ref,
-                     n_groups=n_groups)
+    dicts = {"tri": tri_ref, "quad": quad_ref, "bi": bi_ref}
+    hits = _resident_hits(keys, valid, dicts, match=match)   # stage 5a
+    out_ref[...] = _priority_select(keys, hits)              # stage 5b
 
 
 def _dict_slots(name: str, n_groups: int) -> list:
@@ -240,23 +258,24 @@ def _visit_tables(keys, valid, tiles: sm.DictTileSet, *, n_groups: int,
 
 
 def _ladder_sweep(n, vis_at, keys, valid, dict_ref, dict_bufs, hits_sc,
-                  dma_sems, *, n_groups: int, match: str, num_buffers: int,
+                  dma_sems, *, match: str, num_buffers: int,
                   dict_block_r: int, tri_tiles: int, quad_tiles: int):
     """Stage 5a over a visit list of HBM dictionary tiles: the rotating
     ``num_buffers``-deep make_async_copy ladder, OR-accumulating hits
-    into ``hits_sc``; returns the final hit mask int32[bb, n_slots].
+    into the ``hits_sc`` columns; returns the final hits, bool [bb, 1]
+    per candidate slot.
 
     ``vis_at(k)`` resolves visit ``k`` (of ``n``) to a *global tile id*
     — the grid kernel reads its batch tile's scalar-prefetched row, the
     persistent kernel its descriptor's. The copy for visit
     k + num_buffers - 1 is issued before visit k's compare runs, so
-    tile DMA overlaps the bsearch/bank compute with a tunable lookahead
+    tile DMA overlaps the Compare with a tunable lookahead
     (num_buffers=1 is the no-overlap baseline). Which dictionary a tile
-    feeds is a static boundary compare on its global tile id (not the
-    loop index — the visit list has holes where tiles were skipped).
-    Each tile is internally sorted, so its first/last element still
-    gives the fine [min, max] reject below the pre-pass' coarse one.
+    feeds is a boundary compare on its global tile id (not the loop
+    index — the visit list has holes where tiles were skipped); only
+    that dictionary's candidate columns are compared against it.
     """
+    n_groups = len(keys) // N_CAND
     hits_sc[...] = jnp.zeros_like(hits_sc)
 
     def tile_dma(k, slot):
@@ -278,42 +297,31 @@ def _ladder_sweep(n, vis_at, keys, valid, dict_ref, dict_bufs, hits_sc,
         slot = jax.lax.rem(k, num_buffers)
         tile_dma(k, slot).wait()
         tile_id = vis_at(k)
-        tile = dict_bufs[slot].reshape(-1)         # (dict_block_r * LANE,)
-
-        # which dictionary holds this tile? static boundaries on tile_id
         dict_active = {
             "tri": tile_id < tri_tiles,
             "quad": (tile_id >= tri_tiles) & (tile_id < tri_tiles + quad_tiles),
             "bi": tile_id >= tri_tiles + quad_tiles}
-        slot_active = jnp.concatenate(
-            [jnp.broadcast_to(dict_active[GROUP_DICTS[g]], (N_CAND,))
-             for g in range(n_groups)])            # (n_slots,)
+        for name, active in dict_active.items():
+            slots = _dict_slots(name, n_groups)
+            if not slots:                          # bi with infix=False
+                continue
 
-        # fine tile-range reject: tiles are internally sorted
-        in_range = ((keys >= tile[0]) & (keys <= tile[-1])
-                    & valid & slot_active[None, :])
-
-        @pl.when(in_range.any())
-        def _compare():                            # stage 5a on this tile
-            hit_cols = []
-            for g in range(n_groups):
-                kg = keys[:, g * N_CAND : (g + 1) * N_CAND]
-                hit = (sm.bsearch_hit(tile, kg) if match == "bsearch"
-                       else _bank_hit(tile, kg))
-                hit_cols.append(hit & dict_active[GROUP_DICTS[g]])
-            hits = jnp.concatenate(hit_cols, axis=1) & valid
-            hits_sc[...] |= hits.astype(jnp.int32)
+            @pl.when(active)
+            def _compare(slots=slots):             # stage 5a on this tile
+                member = _membership(dict_bufs.at[slot], match)
+                for s in slots:
+                    hit = (member(keys[s]) & valid[s]).astype(jnp.int32)
+                    hits_sc[:, s:s + 1] = hits_sc[:, s:s + 1] | hit
         return carry
 
     jax.lax.fori_loop(0, n, visit, 0)
-    return hits_sc[...]
+    return [hits_sc[:, s:s + 1] > 0 for s in range(len(keys))]
 
 
-def _fused_pipeline_kernel(nvis_ref, vis_ref, words_ref, dict_ref,
-                           root_ref, src_ref, dict_bufs, hits_sc, dma_sems,
-                           *, n_groups: int, match: str, num_buffers: int,
-                           dict_block_r: int, tri_tiles: int,
-                           quad_tiles: int):
+def _fused_pipeline_kernel(nvis_ref, vis_ref, words_ref, dict_ref, out_ref,
+                           dict_bufs, hits_sc, dma_sems, *, n_groups: int,
+                           match: str, num_buffers: int, dict_block_r: int,
+                           tri_tiles: int, quad_tiles: int):
     """Streamed Compare: grid (batch_tiles,), explicit DMA ladder inside.
 
     The dictionary stream stays in HBM (memory_space=ANY); the kernel
@@ -324,48 +332,42 @@ def _fused_pipeline_kernel(nvis_ref, vis_ref, words_ref, dict_ref,
     keys, valid = _candidates(words_ref[...], n_groups)  # stages 1-4
     hits = _ladder_sweep(
         nvis_ref[i], lambda k: vis_ref[i, k], keys, valid, dict_ref,
-        dict_bufs, hits_sc, dma_sems, n_groups=n_groups, match=match,
-        num_buffers=num_buffers, dict_block_r=dict_block_r,
-        tri_tiles=tri_tiles, quad_tiles=quad_tiles)
-    _priority_select(keys, hits, root_ref, src_ref,
-                     n_groups=n_groups)            # stage 5b
+        dict_bufs, hits_sc, dma_sems, match=match, num_buffers=num_buffers,
+        dict_block_r=dict_block_r, tri_tiles=tri_tiles,
+        quad_tiles=quad_tiles)
+    out_ref[...] = _priority_select(keys, hits)          # stage 5b
 
 
 def _persistent_io(desc_ref, d, words_hbm, words_vm, io_sems, block_b):
-    """Pull descriptor ``d``'s word tile from HBM into VMEM; returns its
-    row offset (descriptor field 0, not the loop index — the ring is
-    addressed through its metadata, so tiles can live anywhere in the
-    queue buffer)."""
-    off = desc_ref[d, 0]
-    cp = pltpu.make_async_copy(words_hbm.at[pl.ds(off, block_b), :],
+    """Pull descriptor ``d``'s transposed word tile from HBM into VMEM;
+    returns its column offset (descriptor field 0, not the loop index —
+    the ring is addressed through its metadata, so tiles can live
+    anywhere in the queue buffer)."""
+    off = pl.multiple_of(desc_ref[d, 0], block_b)
+    cp = pltpu.make_async_copy(words_hbm.at[:, pl.ds(off, block_b)],
                                words_vm, io_sems.at[0])
     cp.start()
     cp.wait()
     return off
 
 
-def _persistent_retire(d, off, desc_ref, root_vm, src_vm, root_hbm, src_hbm,
-                       flags_ref, io_sems, block_b):
-    """Push descriptor ``d``'s finished (root, source) tiles back to HBM
-    and mark its completion flag: 1 + the descriptor's version slot, so
-    the host-side retire can assert every tile completed under the dict
+def _persistent_retire(d, off, desc_ref, out_vm, out_hbm, flags_ref,
+                       io_sems, block_b):
+    """Push descriptor ``d``'s finished output tile back to HBM and mark
+    its completion flag: 1 + the descriptor's version slot, so the
+    host-side retire can assert every tile completed under the dict
     version pinned at dispatch (0 = never processed)."""
-    cp_r = pltpu.make_async_copy(
-        root_vm, root_hbm.at[pl.ds(off, block_b), :], io_sems.at[1])
-    cp_s = pltpu.make_async_copy(
-        src_vm, src_hbm.at[pl.ds(off, block_b), :], io_sems.at[2])
-    cp_r.start()
-    cp_s.start()
-    cp_r.wait()
-    cp_s.wait()
+    cp = pltpu.make_async_copy(out_vm, out_hbm.at[:, pl.ds(off, block_b)],
+                               io_sems.at[1])
+    cp.start()
+    cp.wait()
     flags_ref[d] = 1 + desc_ref[d, 2]
 
 
 def _persistent_streamed_kernel(desc_ref, vis_ref, words_hbm, dict_ref,
-                                root_hbm, src_hbm, flags_ref, words_vm,
-                                root_vm, src_vm, dict_bufs, hits_sc,
-                                dma_sems, io_sems, *, n_groups: int,
-                                match: str, num_buffers: int,
+                                out_hbm, flags_ref, words_vm, out_vm,
+                                dict_bufs, hits_sc, dma_sems, io_sems, *,
+                                n_groups: int, match: str, num_buffers: int,
                                 dict_block_r: int, tri_tiles: int,
                                 quad_tiles: int, block_b: int, n_desc: int):
     """The persistent serving kernel, streamed Compare: ONE launch
@@ -373,14 +375,13 @@ def _persistent_streamed_kernel(desc_ref, vis_ref, words_hbm, dict_ref,
     instead of paying one grid step — or worse, one ``pallas_call`` — per
     batch tile.
 
-    Each descriptor is SMEM metadata ``(row offset, n_visits, version
+    Each descriptor is SMEM metadata ``(column offset, n_visits, version
     slot)``; its word tile is DMA'd from the HBM queue buffer, stages
     1-4 run in VMEM, stage 5a reuses the exact :func:`_ladder_sweep` DMA
-    ladder over the descriptor's visit row, and the (root, source) tiles
-    DMA back to HBM outputs. A per-descriptor completion flag
-    (``1 + version slot``) lands in an SMEM output the host polls — the
-    retire side of the serving ring keeps its non-blocking ``is_ready``
-    contract unchanged.
+    ladder over the descriptor's visit row, and the output tile DMAs
+    back to HBM. A per-descriptor completion flag (``1 + version slot``)
+    lands in an SMEM output the host polls — the retire side of the
+    serving ring keeps its non-blocking ``is_ready`` contract unchanged.
     """
     def tile(d, carry):
         off = _persistent_io(desc_ref, d, words_hbm, words_vm, io_sems,
@@ -388,41 +389,35 @@ def _persistent_streamed_kernel(desc_ref, vis_ref, words_hbm, dict_ref,
         keys, valid = _candidates(words_vm[...], n_groups)   # stages 1-4
         hits = _ladder_sweep(                                # stage 5a
             desc_ref[d, 1], lambda k: vis_ref[d, k], keys, valid, dict_ref,
-            dict_bufs, hits_sc, dma_sems, n_groups=n_groups, match=match,
+            dict_bufs, hits_sc, dma_sems, match=match,
             num_buffers=num_buffers, dict_block_r=dict_block_r,
             tri_tiles=tri_tiles, quad_tiles=quad_tiles)
-        _priority_select(keys, hits, root_vm, src_vm,        # stage 5b
-                         n_groups=n_groups)
-        _persistent_retire(d, off, desc_ref, root_vm, src_vm, root_hbm,
-                           src_hbm, flags_ref, io_sems, block_b)
+        out_vm[...] = _priority_select(keys, hits)           # stage 5b
+        _persistent_retire(d, off, desc_ref, out_vm, out_hbm, flags_ref,
+                           io_sems, block_b)
         return carry
 
     jax.lax.fori_loop(0, n_desc, tile, 0)
 
 
 def _persistent_resident_kernel(desc_ref, words_hbm, tri_ref, quad_ref,
-                                bi_ref, root_hbm, src_hbm, flags_ref,
-                                words_vm, root_vm, src_vm, io_sems, *,
-                                n_groups: int, match: str, block_b: int,
-                                n_desc: int):
+                                bi_ref, out_hbm, flags_ref, words_vm, out_vm,
+                                io_sems, *, n_groups: int, match: str,
+                                block_b: int, n_desc: int):
     """Persistent serving kernel, resident Compare: the packed
     dictionaries sit in VMEM for the whole launch while the descriptor
     loop streams word tiles through; same descriptor/flag contract as
     the streamed variant."""
-    dicts = {"tri": tri_ref[...].reshape(-1),
-             "quad": quad_ref[...].reshape(-1),
-             "bi": bi_ref[...].reshape(-1)}
+    dicts = {"tri": tri_ref, "quad": quad_ref, "bi": bi_ref}
 
     def tile(d, carry):
         off = _persistent_io(desc_ref, d, words_hbm, words_vm, io_sems,
                              block_b)
         keys, valid = _candidates(words_vm[...], n_groups)   # stages 1-4
-        hits = _resident_hits(keys, valid, dicts, n_groups=n_groups,
-                              match=match)                   # stage 5a
-        _priority_select(keys, hits.astype(jnp.int32), root_vm, src_vm,
-                         n_groups=n_groups)                  # stage 5b
-        _persistent_retire(d, off, desc_ref, root_vm, src_vm, root_hbm,
-                           src_hbm, flags_ref, io_sems, block_b)
+        hits = _resident_hits(keys, valid, dicts, match=match)  # 5a
+        out_vm[...] = _priority_select(keys, hits)           # stage 5b
+        _persistent_retire(d, off, desc_ref, out_vm, out_hbm, flags_ref,
+                           io_sems, block_b)
         return carry
 
     jax.lax.fori_loop(0, n_desc, tile, 0)
@@ -517,39 +512,41 @@ def stem_fused_pallas(
     if b == 0:  # degenerate batch: nothing to launch
         empty = (jnp.zeros((0, 4), jnp.int32), jnp.zeros((0,), jnp.int32))
         return empty + (jnp.zeros((0,), jnp.int32),) if persistent else empty
+    if not interpret:
+        _check_tpu_tiling(block_b, dict_block_r, residency)
     pad = (-b) % block_b
     wp = jnp.pad(words, ((0, pad), (0, 0)))
+    wt = wp.T                      # [16, bp]: word tiles travel lane-dense
     bp = wp.shape[0]
     bt = bp // block_b
 
-    word_spec = pl.BlockSpec((block_b, ab.MAXLEN), lambda i, *a: (i, 0))
-    out_specs = [pl.BlockSpec((block_b, 4), lambda i, *a: (i, 0)),
-                 pl.BlockSpec((block_b, 1), lambda i, *a: (i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((bp, 4), jnp.int32),
-                 jax.ShapeDtypeStruct((bp, 1), jnp.int32)]
+    word_spec = pl.BlockSpec((ab.MAXLEN, block_b), lambda i, *a: (0, i))
+    out_spec = pl.BlockSpec((OUT_ROWS, block_b), lambda i, *a: (0, i))
+
+    def split(out):                # [8, rows] output tile -> (root, source)
+        return out[:4, :b].T, out[4, :b]
 
     if residency == "resident":
-        prep = sm.pad_dict_sorted if match == "bsearch" else sm.pad_dict_lanes
-        # infix=False never reads the bi dict: ship a one-lane placeholder
+        # infix=False never reads the bi dict: ship a one-tile placeholder
         # so the unused table doesn't occupy VMEM (see choose_residency)
         bi = roots.bi if infix else jnp.full((1,), sm.DICT_PAD, jnp.int32)
-        tri2, quad2, bi2 = prep(roots.tri), prep(roots.quad), prep(bi)
+        dicts = tuple(sm.pad_dict_tiles(d, RESIDENT_TILE_ROWS)
+                      for d in (roots.tri, roots.quad, bi))
         dict_spec = lambda d: pl.BlockSpec(d.shape, lambda i, *a: (0, 0))
         if persistent:
-            return _persistent_resident_call(
-                wp, (tri2, quad2, bi2), dict_spec, version_slot, b=b,
-                block_b=block_b, n_groups=n_groups, match=match,
-                interpret=interpret)
-        root, source = pl.pallas_call(
+            out, flags = _persistent_resident_call(
+                wt, dicts, dict_spec, version_slot, block_b=block_b,
+                n_groups=n_groups, match=match, interpret=interpret)
+            return split(out) + (flags,)
+        out = pl.pallas_call(
             functools.partial(_fused_kernel, n_groups=n_groups, match=match),
             grid=(bt,),
-            in_specs=[word_spec,
-                      dict_spec(tri2), dict_spec(quad2), dict_spec(bi2)],
-            out_specs=out_specs,
-            out_shape=out_shape,
+            in_specs=[word_spec] + [dict_spec(d) for d in dicts],
+            out_specs=out_spec,
+            out_shape=jax.ShapeDtypeStruct((OUT_ROWS, bp), jnp.int32),
             interpret=interpret,
-        )(wp, tri2, quad2, bi2)
-        return root[:b], source[:b, 0]
+        )(wt, *dicts)
+        return split(out)
 
     # ---- streamed: scalar-prefetched visit index + explicit DMA ladder ---
     if tiles is None or tiles.dict_block_r != dict_block_r:
@@ -573,13 +570,13 @@ def stem_fused_pallas(
     kern_args = dict(n_groups=n_groups, match=match, num_buffers=num_buffers,
                      dict_block_r=dict_block_r, tri_tiles=tri_tiles,
                      quad_tiles=quad_tiles)
-    roots_out, srcs_out, flags_out = [], [], []
+    outs, flags_out = [], []
     for c0 in range(0, bt, max_bt):
         c1 = min(bt, c0 + max_bt)
-        cw = slice(c0 * block_b, c1 * block_b)
+        cw = wt[:, c0 * block_b:c1 * block_b]
         if persistent:
-            r, s, f = _persistent_streamed_call(
-                wp[cw], tiles.stream, n_visits[c0:c1], visit_idx[c0:c1],
+            o, f = _persistent_streamed_call(
+                cw, tiles.stream, n_visits[c0:c1], visit_idx[c0:c1],
                 version_slot, block_b=block_b, n_slots=n_slots,
                 interpret=interpret, **kern_args)
             flags_out.append(f)
@@ -588,8 +585,8 @@ def stem_fused_pallas(
                 num_scalar_prefetch=2,      # (n_visits, visit_idx) -> SMEM
                 grid=(c1 - c0,),
                 in_specs=[word_spec,
-                          pl.BlockSpec(memory_space=pltpu.ANY)],  # dict: HBM
-                out_specs=out_specs,
+                          pl.BlockSpec(memory_space=pl.ANY)],  # dict: HBM
+                out_specs=out_spec,
                 scratch_shapes=[
                     pltpu.VMEM((num_buffers, dict_block_r, sm.LANE),
                                jnp.int32),
@@ -597,91 +594,96 @@ def stem_fused_pallas(
                     pltpu.SemaphoreType.DMA((num_buffers,)),
                 ],
             )
-            r, s = pl.pallas_call(
+            o = pl.pallas_call(
                 functools.partial(_fused_pipeline_kernel, **kern_args),
                 grid_spec=grid_spec,
-                out_shape=[
-                    jax.ShapeDtypeStruct(((c1 - c0) * block_b, 4), jnp.int32),
-                    jax.ShapeDtypeStruct(((c1 - c0) * block_b, 1), jnp.int32),
-                ],
+                out_shape=jax.ShapeDtypeStruct(
+                    (OUT_ROWS, (c1 - c0) * block_b), jnp.int32),
                 interpret=interpret,
-            )(n_visits[c0:c1], visit_idx[c0:c1], wp[cw], tiles.stream)
-        roots_out.append(r)
-        srcs_out.append(s)
-    root = roots_out[0] if len(roots_out) == 1 else jnp.concatenate(roots_out)
-    source = srcs_out[0] if len(srcs_out) == 1 else jnp.concatenate(srcs_out)
+            )(n_visits[c0:c1], visit_idx[c0:c1], cw, tiles.stream)
+        outs.append(o)
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
     if persistent:
         flags = (flags_out[0] if len(flags_out) == 1
                  else jnp.concatenate(flags_out))
-        return root[:b], source[:b, 0], flags
-    return root[:b], source[:b, 0]
+        return split(out) + (flags,)
+    return split(out)
+
+
+def _check_tpu_tiling(block_b: int, dict_block_r: int, residency: str):
+    """Compiled kernels slice word tiles along lanes and dictionary tiles
+    along sublanes: both must cover whole (8, 128) vreg tiles."""
+    if block_b % sm.LANE:
+        raise ValueError(f"block_b={block_b} must be a multiple of"
+                         f" {sm.LANE} on a TPU")
+    if residency == "streamed" and dict_block_r % 8:
+        raise ValueError(f"dict_block_r={dict_block_r} must be a multiple"
+                         " of 8 on a TPU")
 
 
 def _descriptors(bt: int, block_b: int, n_visits, version_slot):
-    """Pack the work-descriptor ring: int32[bt, 3] of (row offset,
+    """Pack the work-descriptor ring: int32[bt, 3] of (column offset,
     n_visits, version slot) per tile, delivered via scalar prefetch."""
     ver = jnp.broadcast_to(jnp.asarray(version_slot, jnp.int32), (bt,))
     offs = jnp.arange(bt, dtype=jnp.int32) * block_b
     return jnp.stack([offs, n_visits.astype(jnp.int32), ver], axis=1)
 
 
-def _persistent_resident_call(wp, dicts, dict_spec, version_slot, *, b: int,
+def _persistent_out_shape(bp: int, bt: int):
+    return [jax.ShapeDtypeStruct((OUT_ROWS, bp), jnp.int32),
+            jax.ShapeDtypeStruct((bt,), jnp.int32)]
+
+
+def _persistent_resident_call(wt, dicts, dict_spec, version_slot, *,
                               block_b: int, n_groups: int, match: str,
                               interpret: bool):
-    bp = wp.shape[0]
+    bp = wt.shape[1]
     bt = bp // block_b
     desc = _descriptors(bt, block_b, jnp.zeros(bt, jnp.int32), version_slot)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,              # descriptor ring -> SMEM
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] + [
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + [
             dict_spec(d) for d in dicts],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
                    pl.BlockSpec(memory_space=pltpu.SMEM)],
         scratch_shapes=[
-            pltpu.VMEM((block_b, ab.MAXLEN), jnp.int32),
-            pltpu.VMEM((block_b, 4), jnp.int32),
-            pltpu.VMEM((block_b, 1), jnp.int32),
-            pltpu.SemaphoreType.DMA((3,)),
+            pltpu.VMEM((ab.MAXLEN, block_b), jnp.int32),
+            pltpu.VMEM((OUT_ROWS, block_b), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    root, source, flags = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_persistent_resident_kernel, n_groups=n_groups,
                           match=match, block_b=block_b, n_desc=bt),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((bp, 4), jnp.int32),
-                   jax.ShapeDtypeStruct((bp, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((bt,), jnp.int32)],
+        out_shape=_persistent_out_shape(bp, bt),
         interpret=interpret,
-    )(desc, wp, *dicts)
-    return root[:b], source[:b, 0], flags
+    )(desc, wt, *dicts)
 
 
-def _persistent_streamed_call(wp, stream, n_visits, visit_idx, version_slot,
+def _persistent_streamed_call(wt, stream, n_visits, visit_idx, version_slot,
                               *, block_b: int, n_slots: int, n_groups: int,
                               match: str, num_buffers: int, dict_block_r: int,
                               tri_tiles: int, quad_tiles: int,
                               interpret: bool):
-    bp = wp.shape[0]
+    bp = wt.shape[1]
     bt = bp // block_b
     desc = _descriptors(bt, block_b, n_visits, version_slot)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,              # (descriptors, visit rows)
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),   # word queue: HBM
-                  pl.BlockSpec(memory_space=pltpu.ANY)],  # dict: HBM
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),   # word queue: HBM
+                  pl.BlockSpec(memory_space=pl.ANY)],  # dict: HBM
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
                    pl.BlockSpec(memory_space=pltpu.SMEM)],
         scratch_shapes=[
-            pltpu.VMEM((block_b, ab.MAXLEN), jnp.int32),
-            pltpu.VMEM((block_b, 4), jnp.int32),
-            pltpu.VMEM((block_b, 1), jnp.int32),
+            pltpu.VMEM((ab.MAXLEN, block_b), jnp.int32),
+            pltpu.VMEM((OUT_ROWS, block_b), jnp.int32),
             pltpu.VMEM((num_buffers, dict_block_r, sm.LANE), jnp.int32),
             pltpu.VMEM((block_b, n_slots), jnp.int32),
             pltpu.SemaphoreType.DMA((num_buffers,)),
-            pltpu.SemaphoreType.DMA((3,)),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     return pl.pallas_call(
@@ -690,11 +692,9 @@ def _persistent_streamed_call(wp, stream, n_visits, visit_idx, version_slot,
                           dict_block_r=dict_block_r, tri_tiles=tri_tiles,
                           quad_tiles=quad_tiles, block_b=block_b, n_desc=bt),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((bp, 4), jnp.int32),
-                   jax.ShapeDtypeStruct((bp, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((bt,), jnp.int32)],
+        out_shape=_persistent_out_shape(bp, bt),
         interpret=interpret,
-    )(desc, visit_idx, wp, stream)
+    )(desc, visit_idx, wt, stream)
 
 
 def salvage_descriptor_rows(flags, version_slot: int, block_b: int) -> int:

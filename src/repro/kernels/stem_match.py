@@ -40,13 +40,6 @@ def _ceil_log2(n: int) -> int:
     return k
 
 
-def pad_dict_lanes(dict_keys: jnp.ndarray) -> jnp.ndarray:
-    """Pad to a LANE multiple with DICT_PAD and reshape (rows, LANE)."""
-    r = dict_keys.shape[0]
-    r_pad = (-r) % LANE
-    return jnp.pad(dict_keys, (0, r_pad), constant_values=DICT_PAD).reshape(-1, LANE)
-
-
 def pad_dict_sorted(dict_keys: jnp.ndarray) -> jnp.ndarray:
     """Pad a *sorted* dictionary to the next pow2 >= LANE with DICT_SENTINEL,
     reshaped (rows, LANE) so it ships to VMEM as a lane-aligned 2D tile."""
@@ -61,9 +54,9 @@ def pad_dict_tiles(dict_keys: jnp.ndarray, tile_rows: int) -> jnp.ndarray:
     with DICT_SENTINEL and reshape (n_tiles * tile_rows, LANE).
 
     Sentinel padding on the right keeps every tile internally sorted, so a
-    consumer can binary-search each tile independently and use the tile's
-    first/last element as a [min, max] range reject (the streamed megakernel
-    Compare path, stem_fused._fused_pipeline_kernel). Empty / placeholder
+    consumer can search each tile independently (:func:`sorted_member`)
+    and bound it by its first/last element (the streamed megakernel's
+    tile-visit pre-pass, stem_fused._visit_tables). Empty / placeholder
     dictionaries still produce one full sentinel tile.
     """
     r = dict_keys.shape[0]
@@ -125,6 +118,70 @@ def build_dict_tiles(tri: jnp.ndarray, quad: jnp.ndarray, bi: jnp.ndarray,
     flat = stream.reshape(-1, dict_block_r * LANE)   # one row per tile
     return DictTileSet(stream=stream, mins=flat[:, 0], maxs=flat[:, -1],
                        dict_block_r=dict_block_r, counts=counts)
+
+
+# 8-bit planes a sorted key row is split into for the MXU row fetch: four
+# cover any non-negative int32 (sentinels included), and every plane value
+# (0..255) is exact in bf16
+PLANES = 4
+
+
+def _bf16(x: jnp.ndarray) -> jnp.ndarray:
+    return x.astype(jnp.float32).astype(jnp.bfloat16)
+
+
+def sorted_tables(rows: jnp.ndarray):
+    """rows int32[n, LANE] (a sorted, sentinel-padded dictionary in
+    row-major order, :func:`pad_dict_tiles`) -> the tables of
+    :func:`sorted_member`: the row maxima int32[1, n] and the rows' 8-bit
+    planes bf16[n, PLANES * LANE]."""
+    row_max = rows.T[LANE - 1:, :]
+    planes = jnp.concatenate(
+        [_bf16((rows >> (8 * p)) & 255) for p in range(PLANES)], axis=1)
+    return row_max, planes
+
+
+def sorted_member(tables, keys: jnp.ndarray) -> jnp.ndarray:
+    """Gather-free membership in a sorted dictionary (the in-kernel
+    ``match="bsearch"`` Compare): keys int32[m, 1] -> bool[m, 1].
+
+    Two levels replace the bisection's data-dependent loads, which
+    Mosaic cannot lower:
+
+      1. the key's row is the number of rows whose last (largest) entry
+         is below it — one compare against the row maxima;
+      2. that row is fetched with a one-hot ``[m, n] @ [n, PLANES*LANE]``
+         MXU matmul over the rows' 8-bit planes (bf16 in, f32 out: each
+         output sums exactly one plane value, so the fetch is exact), and
+         the key is compared against the fetched row's LANE entries.
+
+    Bit-identical to :func:`bsearch_hit` on the same dictionary.
+    """
+    row_max, planes = tables
+    n = row_max.shape[1]
+    row = jnp.minimum(jnp.sum((row_max < keys).astype(jnp.int32), axis=1,
+                              keepdims=True), n - 1)             # (m, 1)
+    onehot = _bf16(jax.lax.broadcasted_iota(jnp.int32, (keys.shape[0], n), 1)
+                   == row)
+    f = jnp.dot(onehot, planes,
+                preferred_element_type=jnp.float32).astype(jnp.int32)
+    val = f[:, :LANE]
+    for p in range(1, PLANES):
+        val = val | (f[:, p * LANE:(p + 1) * LANE] << (8 * p))
+    return jnp.any(val == keys, axis=1, keepdims=True)
+
+
+def bank_rows_member(rows_ref, keys: jnp.ndarray) -> jnp.ndarray:
+    """All-pairs membership (``match="bank"``): keys int32[m, 1] against
+    every row of the (rows, LANE) dictionary ref -> bool[m, 1], one
+    ``[m, LANE]`` comparator bank per row in a loop (the whole
+    ``[m, R]`` compare would not fit VMEM at lexicon sizes)."""
+    def row(r, hit):
+        eq = keys == rows_ref[pl.ds(r, 1), :]
+        return hit | jnp.any(eq, axis=1, keepdims=True).astype(jnp.int32)
+
+    return jax.lax.fori_loop(0, rows_ref.shape[0], row,
+                             jnp.zeros(keys.shape, jnp.int32)) > 0
 
 
 def bsearch_hit(flat_dict: jnp.ndarray, keys: jnp.ndarray) -> jnp.ndarray:

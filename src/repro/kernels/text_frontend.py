@@ -1,28 +1,26 @@
 """Pallas TPU kernel: the raw-text ingestion front-end (DESIGN.md §7).
 
-One launch turns a padded codepoint tile into normalised, clitic-stripped
+One launch turns per-word class windows into normalised, clitic-stripped
 `[block_w, 16]` word-tile rows — the exact input `stem_fused_pallas`
 consumes — so text feeds the stemmer megakernel with no host round-trip:
 
   grid = (Wp / block_w,)   one step per word tile
-  chars     VMEM-resident (constant index map), gathered per word
-  starts    int32[Wp, 1]   word start char indices (geometry pre-pass)
-  lens      int32[Wp, 1]   raw codepoint counts
-  lut       (2, 128)       textnorm.CLASS_LUT as a lane-aligned tile
-  fw        (Fp/128, 128)  textnorm.FW_FLAT function-word keys (sorted,
-                           sentinel-padded pow2 — pad_dict_sorted layout)
+  win       int32[Wp, 32]  each word's first MAX_RAW codepoint classes
+                           (textnorm.class_windows, an XLA gather)
+  fw        (r, 128)       textnorm.FW_ROWS function-word keys
 
-The kernel body is gather-based where the jnp reference
-(``textnorm.frontend_reference``) is scatter-based: each word reads its
-MAX_RAW-codepoint raw window with one ``jnp.take``, classifies through
-the LUT, compacts letters with the unrolled cumsum==k one-hot pattern
-(no in-kernel argsort/gather along traced offsets), then hands the
-letter rows to the *shared* ``textnorm.strip_and_pack`` body — the same
-traced code both paths run, so clitic stripping cannot drift between
-reference and kernel. Word geometry (starts/lens/byte spans) comes from
-``textnorm.segment_geometry``, a jnp pre-pass in the same jit scope —
-the PR 5 visit-index precedent: cheap irregular indexing work stays in
-XLA, the dense per-word normalisation runs in the kernel.
+The irregular part — reading each word's raw window out of the
+codepoint tile at a data-dependent offset — stays in XLA: Mosaic lowers
+no gather across vregs. Word geometry (starts/lens/byte spans) comes
+from ``textnorm.segment_geometry`` and the windows from
+``textnorm.class_windows``, both jnp pre-passes in the same jit scope
+(the PR 5 visit-index precedent). The kernel does the dense per-word
+work: it compacts letters left with an inclusive letter count (a small
+triangular matmul on the MXU, exact for counts <= 32) and the
+count==k one-hot pattern, then hands the letter rows to the *shared*
+``textnorm.strip_and_pack`` body — the same traced code the jnp
+reference (``textnorm.frontend_reference``) runs, so clitic stripping
+cannot drift between reference and kernel.
 """
 from __future__ import annotations
 
@@ -35,35 +33,25 @@ from jax.experimental import pallas as pl
 from repro.core import alphabet as ab
 from repro.core import textnorm as tn
 
-LANE = 128
 
 
-def _frontend_kernel(starts_ref, lens_ref, chars_ref, lut_ref, fw_ref,
-                     words_ref):
-    starts = starts_ref[...][:, 0]                 # [bw]
-    lens = lens_ref[...][:, 0]                     # [bw]
-    flat = chars_ref[...].reshape(-1)              # [Tp]
-    lut = lut_ref[...].reshape(-1)                 # [256]
-    fw = fw_ref[...].reshape(-1)                   # [Fp]
-    bw = starts.shape[0]
-
-    j = jax.lax.broadcasted_iota(jnp.int32, (bw, tn.MAX_RAW), 1)
-    idx = starts[:, None] + j
-    raw = jnp.take(flat, jnp.clip(idx, 0, flat.shape[0] - 1), mode="clip")
-    live = j < jnp.minimum(lens, tn.MAX_RAW)[:, None]
-    cls = jnp.where(live, tn.classify_codes(raw, lut), tn.CLS_SEP)
-
-    # compact letters left: position k letter = the column whose running
-    # letter count hits k+1 (cumsum one-hot; same trick as the fused
-    # kernel's _priority_select — no gather along traced offsets)
+def _frontend_kernel(win_ref, fw_ref, words_ref):
+    cls = win_ref[...]                             # [bw, MAX_RAW]
     is_letter = cls > 0
-    csum = jnp.cumsum(is_letter.astype(jnp.int32), axis=1)
-    nlet = jnp.minimum(csum[:, -1], tn.CMAX)
-    cols = [jnp.sum(jnp.where(is_letter & (csum == k + 1), cls, 0), axis=1)
-            for k in range(tn.CMAX)]
-    codes = jnp.stack(cols, axis=1)                # [bw, CMAX]
-
-    words_ref[...] = tn.strip_and_pack(codes, nlet, fw)
+    # inclusive running letter count along the window: a triangular
+    # matmul (0/1 operands, counts <= MAX_RAW: exact in bf16 -> f32)
+    r = jax.lax.broadcasted_iota(jnp.int32, (tn.MAX_RAW, tn.MAX_RAW), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (tn.MAX_RAW, tn.MAX_RAW), 1)
+    upper = (r <= c).astype(jnp.float32).astype(jnp.bfloat16)
+    csum = jnp.dot(is_letter.astype(jnp.float32).astype(jnp.bfloat16), upper,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    nlet = jnp.minimum(csum[:, tn.MAX_RAW - 1], tn.CMAX)
+    # compact letters left: position k letter = the column whose running
+    # letter count hits k+1 (one-hot sum — no gather along traced offsets)
+    codes = jnp.concatenate(
+        [jnp.sum(jnp.where(is_letter & (csum == k + 1), cls, 0), axis=1,
+                 keepdims=True) for k in range(tn.CMAX)], axis=1)
+    words_ref[...] = tn.strip_and_pack(codes, nlet, fw_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
@@ -81,22 +69,17 @@ def text_frontend_pallas(chars, starts, lens, *, block_w: int = 128,
     if wp % block_w:
         raise ValueError(f"word capacity {wp} not a multiple of"
                          f" block_w={block_w}")
-    t_pad = (-chars.shape[0]) % LANE
-    chars2 = jnp.pad(chars, (0, t_pad)).reshape(-1, LANE)
-    lut2 = jnp.asarray(tn.CLASS_LUT).reshape(2, LANE)
-    fw2 = jnp.asarray(tn.FW_FLAT).reshape(-1, LANE)
+    win = tn.class_windows(chars, starts, lens)
+    fw = jnp.asarray(tn.FW_ROWS)
 
     return pl.pallas_call(
         _frontend_kernel,
         grid=(wp // block_w,),
         in_specs=[
-            pl.BlockSpec((block_w, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_w, 1), lambda i: (i, 0)),
-            pl.BlockSpec(chars2.shape, lambda i: (0, 0)),
-            pl.BlockSpec(lut2.shape, lambda i: (0, 0)),
-            pl.BlockSpec(fw2.shape, lambda i: (0, 0)),
+            pl.BlockSpec((block_w, tn.MAX_RAW), lambda i: (i, 0)),
+            pl.BlockSpec(fw.shape, lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_w, ab.MAXLEN), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((wp, ab.MAXLEN), jnp.int32),
         interpret=interpret,
-    )(starts[:, None], lens[:, None], chars2, lut2, fw2)
+    )(win, fw)

@@ -8,18 +8,26 @@ while tests and benches see the real single device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the installed JAX defaults to
+    Explicit axes, whose sharding-in-types refuses the shard_map + slice
+    and gather code of the sharded serving and indexing paths."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1):
     """Small mesh over the actually-available devices (tests/examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))
 
 
 def make_data_mesh(n_dev: int | None = None):
@@ -35,4 +43,4 @@ def make_data_mesh(n_dev: int | None = None):
     if not 1 <= n_dev <= avail:
         raise ValueError(
             f"data mesh needs 1 <= n_dev <= {avail} devices, got {n_dev}")
-    return jax.make_mesh((n_dev,), ("data",))
+    return _mesh((n_dev,), ("data",))

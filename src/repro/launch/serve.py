@@ -7,12 +7,14 @@
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_mod
 from repro.models import params as pm
 from repro.serve import (DegradationPolicy, DictStore, Engine, Journal,
@@ -62,9 +64,12 @@ def _report_events(eng) -> None:
                   f" ({ev.data['reason']})")
 
 
-def _report_failures(eng, rids) -> str:
-    failed = [eng.result(r) for r in rids]
-    failed = [r for r in failed if r is not None and r.failure is not None]
+def _failed(eng, rids) -> list:
+    reqs = [eng.result(r) for r in rids]
+    return [r for r in reqs if r is not None and r.failure is not None]
+
+
+def _report_failures(failed, eng) -> str:
     for req in failed[:4]:
         print(f"  req {req.rid} FAILED: {req.failure.code}"
               f" ({req.failure.detail})")
@@ -100,12 +105,14 @@ def serve_lm(args) -> None:
     ]
     rep = eng.run_until_drained()
     dt = time.time() - t0
+    failed = _failed(eng, rids)
     total_tokens = sum(len(eng.result(r).tokens_out) for r in rids)
     print(f"served {args.requests} requests / {total_tokens} tokens in "
           f"{dt:.2f}s ({total_tokens / dt:.1f} tok/s, {rep.ticks} ticks, "
-          f"cache_len {cache_len}{_report_failures(eng, rids)})")
+          f"cache_len {cache_len}{_report_failures(failed, eng)})")
     for rid in rids[:4]:
         print(f"  req {rid}: {eng.result(rid).tokens_out}")
+    return len(failed)
 
 
 def serve_stemmer(args) -> None:
@@ -136,6 +143,7 @@ def serve_stemmer(args) -> None:
             for i in range(args.requests)]
     rep = eng.run_until_drained()
     dt = time.time() - t0
+    failed = _failed(eng, rids)
     n_words = args.requests * wpr
     print(f"served {args.requests} word-batch requests / {n_words} words in "
           f"{dt:.2f}s ({n_words / dt:.1f} Wps, {rep.ticks} ticks, "
@@ -143,13 +151,14 @@ def serve_stemmer(args) -> None:
           f"super-tile {args.devices}x{args.block_b}, "
           f"megabatch {args.megabatch}"
           f"{', persistent' if args.persistent else ''}, "
-          f"inflight {args.inflight}{_report_failures(eng, rids)})")
+          f"inflight {args.inflight}{_report_failures(failed, eng)})")
     _report_events(eng)
     for rid in rids[:2]:
         req = eng.result(rid)
         if req.failure is None:
             print(f"  req {rid}: {req.n_words} roots,"
                   f" dict v{req.dict_version}")
+    return len(failed)
 
 
 def build_documents(n_docs: int, words_per_doc: int, seed: int = 1):
@@ -196,13 +205,14 @@ def serve_text(args) -> None:
     rids = [eng.submit(doc, deadline_s=_deadline_s(args)) for doc in docs]
     rep = eng.run_until_drained()
     dt = time.time() - t0
+    failed = _failed(eng, rids)
     n_words = sum(eng.result(r).n_words for r in rids)
     print(f"served {args.requests} documents / {n_bytes} bytes /"
           f" {n_words} words in {dt:.2f}s ({n_bytes / dt:.0f} B/s,"
           f" {n_words / dt:.1f} Wps, {rep.ticks} ticks,"
           f" {eng.workload.ticks_launched} launches,"
           f" frontend {args.frontend}, megabatch {args.megabatch},"
-          f" inflight {args.inflight}{_report_failures(eng, rids)})")
+          f" inflight {args.inflight}{_report_failures(failed, eng)})")
     _report_events(eng)
     for rid in rids[:2]:
         req = eng.result(rid)
@@ -211,6 +221,7 @@ def serve_text(args) -> None:
         root, src, span = req.analyses()[0][0]
         print(f"  req {rid}: {req.n_words} tokens, first root {root!r}"
               f" (src {src}, bytes {span})")
+    return len(failed)
 
 
 def main():
@@ -326,12 +337,13 @@ def main():
         ap.error("--degrade applies to the stemmer/text workloads (the"
                  " LM decode loop has no mode ladder)")
 
-    if args.workload == "stemmer":
-        serve_stemmer(args)
-    elif args.workload == "text":
-        serve_text(args)
-    else:
-        serve_lm(args)
+    enable_compile_cache()
+    serve = {"stemmer": serve_stemmer, "text": serve_text}.get(
+        args.workload, serve_lm)
+    n_failed = serve(args)
+    if n_failed:
+        print(f"{n_failed} request(s) failed", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

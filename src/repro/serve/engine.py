@@ -64,6 +64,24 @@ from repro.models import model as model_mod
 from repro.serve.faults import DeviceLost, FailureInfo
 from repro.serve.health import EventLog
 
+# JaxRuntimeError messages of a launch the TPU compiler refused
+_COMPILE_FAILURES = ("Mosaic failed to compile", "memory space vmem",
+                     "UNIMPLEMENTED")
+
+
+def _deterministic_launch_error(exc: BaseException) -> bool:
+    """True for a launch that failed to trace, lower or compile: a
+    TypeError / ValueError / Pallas lowering error (not RuntimeErrors), a
+    NotImplementedError (a lowering rule Mosaic lacks), or a
+    JaxRuntimeError from the compiler. Such a launch fails identically
+    on every retry, so retrying it would only quarantine healthy
+    requests and hide the fault."""
+    if (not isinstance(exc, RuntimeError)
+            or isinstance(exc, NotImplementedError)):
+        return True
+    return (isinstance(exc, jax.errors.JaxRuntimeError)
+            and any(m in str(exc) for m in _COMPILE_FAILURES))
+
 
 # ---------------------------------------------------------------------------
 # the workload contract
@@ -598,13 +616,10 @@ class InflightTile:
         launch), so it is ready exactly when they are — and the retire
         tick busy-polls this, so every extra is_ready() call here is paid
         hundreds of times per drain."""
-        try:
-            return bool(self.roots_dev.is_ready()
-                        and self.sources_dev.is_ready()
-                        and (self.flags_dev is None
-                             or self.flags_dev.is_ready()))
-        except AttributeError:   # backend without readiness introspection
-            return True
+        return bool(self.roots_dev.is_ready()
+                    and self.sources_dev.is_ready()
+                    and (self.flags_dev is None
+                         or self.flags_dev.is_ready()))
 
 
 @dataclass
@@ -1043,6 +1058,12 @@ class StemmerWorkload:
             n += self._dispatch_group(grp)
         return n
 
+    @staticmethod
+    def _unclaim(grp: RetryGroup) -> None:
+        """Return a group's words to their requests, undispatched."""
+        for req, _r0, take in grp.segments:
+            req.dispatched -= take
+
     def _launch_failed(self, grp: RetryGroup, exc: BaseException) -> int:
         """Shared failure path for dispatch errors, timeouts, and retire
         checksum mismatches: retry with backoff, bisect after
@@ -1050,8 +1071,7 @@ class StemmerWorkload:
         if self.max_retries == 0:
             # strict mode: unwind the claims so every word is
             # re-coalesced from scratch, and propagate to the caller
-            for req, _r0, take in grp.segments:
-                req.dispatched -= take
+            self._unclaim(grp)
             raise exc
         grp.retries += 1
         self.retries_total += 1
@@ -1165,9 +1185,15 @@ class StemmerWorkload:
         except BaseException as e:
             # a failed launch must not wedge the engine: return the slot
             # and route the group through the retry machinery (strict
-            # mode re-raises with the words unclaimed)
+            # mode re-raises with the words unclaimed). A launch that
+            # failed to trace, lower or compile fails the same way on
+            # every attempt: it propagates with the words unclaimed
+            # instead of being retried into quarantine.
             self._free_slots.append(slot)
             if isinstance(e, (KeyboardInterrupt, SystemExit)):
+                raise
+            if _deterministic_launch_error(e):
+                self._unclaim(grp)
                 raise
             return self._launch_failed(grp, e)
         entry = InflightTile(placed, dv.version, roots, sources, slot,
@@ -1179,15 +1205,9 @@ class StemmerWorkload:
             # a wedge is observable only through the completion flags,
             # so the stall site covers persistent launches alone
             entry.stalled = self.injector.on_stall()
-        try:                            # start D2H early; retire just reads
-            roots.copy_to_host_async()
-            sources.copy_to_host_async()
-            if flags is not None:
-                flags.copy_to_host_async()
-            if checksums is not None:
-                checksums.copy_to_host_async()
-        except AttributeError:
-            pass
+        for arr in (roots, sources, flags, checksums):
+            if arr is not None:         # start D2H early; retire just reads
+                arr.copy_to_host_async()
         self.ring.append(entry)
         self.ticks_launched += 1
         return 1

@@ -475,3 +475,20 @@ def test_serve_launcher_rejects_bad_flag_combos(argv, monkeypatch):
     with pytest.raises(SystemExit) as exc:
         serve_mod.main()
     assert exc.value.code == 2          # argparse .error(), pre-engine
+
+
+def test_serve_launcher_exits_nonzero_on_failed_requests(monkeypatch,
+                                                         capsys):
+    """A run that finished with failed requests is not a success: the
+    launcher reports them and exits 1 (here every request misses a
+    1 µs deadline)."""
+    from repro.launch import serve as serve_mod
+
+    monkeypatch.setattr(serve_mod, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr("sys.argv", [
+        "serve.py", "--workload", "stemmer", "--requests", "2",
+        "--words-per-request", "8", "--deadline-ms", "0.001"])
+    with pytest.raises(SystemExit) as exc:
+        serve_mod.main()
+    assert exc.value.code == 1
+    assert "2 request(s) failed" in capsys.readouterr().err

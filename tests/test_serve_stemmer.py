@@ -230,6 +230,52 @@ def test_failed_launch_leaves_engine_recoverable(dict_and_words,
     np.testing.assert_array_equal(got2, np.asarray(want_r))
 
 
+def _trace_error(*a, **kw):
+    return jnp.dot(jnp.ones((2, 3)), jnp.ones((4, 5)))   # TypeError
+
+
+def _lowering_error(*a, **kw):
+    raise NotImplementedError("Only 2D gather is supported")
+
+
+def _compile_error(*a, **kw):
+    import jax
+
+    raise jax.errors.JaxRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel: unsupported shape"
+        " cast")
+
+
+@pytest.mark.parametrize("broken", [_trace_error, _lowering_error,
+                                    _compile_error])
+def test_launch_that_cannot_compile_propagates_unretried(dict_and_words,
+                                                         monkeypatch, broken):
+    """A launch that fails to trace, lower or compile fails the same way
+    on every attempt: with retries enabled it still propagates out of the
+    engine on the first attempt — no retry, no quarantine — with the
+    slot returned and the words unclaimed, so the engine drains once the
+    launch is fixed."""
+    from repro.kernels import ops
+
+    arrays, enc = dict_and_words
+    eng = Engine(StemmerWorkload(DictStore(arrays), block_b=16,
+                                 max_inflight=2))
+    rids = [eng.submit(enc[i * 16:(i + 1) * 16]) for i in range(2)]
+    monkeypatch.setattr(ops, "extract_roots_fused", broken)
+    with pytest.raises((TypeError, NotImplementedError, RuntimeError)):
+        eng.step()
+    w = eng.workload
+    assert w.retries_total == 0
+    assert not [ev for ev in eng.events() if ev.kind in ("retry", "failure")]
+    assert len(w._free_slots) == 2
+    assert all(r.dispatched == 0 for r in w.inflight)
+    monkeypatch.undo()
+    assert eng.run_until_drained().drained
+    want_r, _ = stemmer.stem_batch(jnp.asarray(enc[:32]), arrays)
+    got_r = np.concatenate([eng.result(r).roots for r in rids])
+    np.testing.assert_array_equal(got_r, np.asarray(want_r))
+
+
 def test_overlap_parity_with_sync(dict_and_words):
     """Depth-4 overlapped serving returns exactly what the synchronous
     tick returns, request by request."""

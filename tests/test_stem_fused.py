@@ -117,6 +117,26 @@ def test_bsearch_hit_boundaries():
         got, [False, True, False, True, False, True, True, False])
 
 
+@pytest.mark.parametrize("r", [1, 127, 128, 1025, 5000])
+def test_sorted_member_matches_bisection(r):
+    """The in-kernel two-level search (row-max count + one-hot MXU row
+    fetch) answers exactly what the bisection answers — first and last
+    keys, keys between rows, keys past the sentinel padding, and keys
+    whose high byte is set."""
+    rng = np.random.default_rng(r)
+    d = np.unique(rng.integers(1, 2**24, size=r)).astype(np.int32)
+    probes = np.concatenate([
+        d, d - 1, d + 1, [0, 1, 2**24 - 1, sm.DICT_SENTINEL - 1],
+        rng.integers(0, 2**24, size=300)]).astype(np.int32)
+    rows = sm.pad_dict_tiles(jnp.asarray(d), 8)
+    got = sm.sorted_member(sm.sorted_tables(rows),
+                           jnp.asarray(probes)[:, None])[:, 0]
+    want = sm.bsearch_hit(sm.pad_dict_sorted(jnp.asarray(d)).reshape(-1),
+                          jnp.asarray(probes))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got), np.isin(probes, d))
+
+
 # ---------------------------------------------------------------------------
 # fused backend through the public APIs
 # ---------------------------------------------------------------------------
@@ -187,3 +207,38 @@ def test_autotune_returns_valid_config(dicts):
                                   interpret=True)
     assert cfg["block_b"] in (64, 128) and cfg["match"] == "bsearch"
     assert all(t > 0 for t in cfg["timings"].values())
+
+
+_LOWER_SCRIPT = """
+import hashlib
+import jax, jax.numpy as jnp
+from repro.core import corpus, stemmer
+from repro.kernels import stem_fused as sf
+d = stemmer.RootDictArrays.from_rootdict(
+    corpus.build_dictionary(n_tri=60, n_quad=12, seed=0))
+w = jax.ShapeDtypeStruct((32, 16), jnp.int32)
+text = jax.jit(lambda w, r: sf.stem_fused_pallas(
+    w, r, block_b=32, residency="resident", interpret=True)).lower(
+    w, d).as_text()
+print(hashlib.sha256(text.encode()).hexdigest())
+"""
+
+
+def test_megakernel_lowers_identically_across_processes():
+    """The persistent compilation cache keys on the lowered program, so
+    the kernel must trace in the same order in every process — no
+    iteration over a set of strings, whose order follows the
+    per-process hash seed."""
+    import os
+    import subprocess
+    import sys
+
+    digests = set()
+    for seed in ("1", "2", "3"):
+        env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu")
+        proc = subprocess.run([sys.executable, "-c", _LOWER_SCRIPT],
+                              env=env, capture_output=True, text=True,
+                              timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        digests.add(proc.stdout.strip().splitlines()[-1])
+    assert len(digests) == 1

@@ -157,12 +157,14 @@ def test_function_word_exemption_vs_stripping():
 
 
 def test_fw_table_layout():
-    # sorted, unique, sentinel-padded pow2 >= one lane row
-    assert tn.FW_FLAT.shape[0] >= 128
-    assert tn.FW_FLAT.shape[0] & (tn.FW_FLAT.shape[0] - 1) == 0
+    # sorted, unique, sentinel-padded to whole 128-lane rows
+    assert tn.FW_ROWS.ndim == 2 and tn.FW_ROWS.shape[1] == 128
+    flat = tn.FW_ROWS.reshape(-1)
     keys = tn.FW_KEYS
     assert (np.diff(keys) > 0).all()
-    assert (tn.FW_FLAT[len(keys):] == tn.FW_SENTINEL).all()
+    np.testing.assert_array_equal(flat[:len(keys)], keys)
+    assert len(flat) - len(keys) < 128
+    assert (flat[len(keys):] == tn.FW_SENTINEL).all()
     assert int(keys[-1]) < int(tn.FW_SENTINEL)
 
 
