@@ -7,7 +7,8 @@ fault-injection harness (FaultPlan/FaultInjector) and the structured
 FailureInfo that terminally failed requests carry. ``journal`` is the
 write-ahead request log behind ``Engine.recover`` (crash-safe warm
 restart); ``health`` is the structured event stream plus the
-graceful-degradation ladder (DESIGN.md §12).
+graceful-degradation ladder (DESIGN.md §12). ``spans`` names the
+profiler spans the engine and the text front end open.
 """
 from repro.serve.dict_store import (DictSnapshotError, DictStore,
                                     DictValidationError, DictVersion,

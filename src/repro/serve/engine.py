@@ -63,6 +63,9 @@ from repro.core import alphabet as ab
 from repro.models import model as model_mod
 from repro.serve.faults import DeviceLost, FailureInfo
 from repro.serve.health import EventLog
+from repro.serve.spans import (ENGINE_STEP, ENGINE_SUBMIT, STEM_COALESCE,
+                               STEM_FETCH, STEM_LAUNCH, STEM_SCATTER,
+                               STEM_STAGE, STEM_VERIFY, span)
 
 # JaxRuntimeError messages of a launch the TPU compiler refused
 _COMPILE_FAILURES = ("Mosaic failed to compile", "memory space vmem",
@@ -207,44 +210,45 @@ class Engine:
 
     def submit(self, payload, *, deadline_s: float | None = None,
                **opts) -> int:
-        if deadline_s is not None and deadline_s <= 0:
-            raise ValueError(f"deadline_s must be > 0, got {deadline_s}")
-        if self._queue_full():
-            if self.on_full == "raise":
-                raise QueueFull(
-                    f"queue at cap {self.queue_cap}; submit rejected"
-                    " (on_full='raise')")
-            if self.on_full == "block":
-                for _ in range(100_000):
-                    self.step()
-                    if not self._queue_full():
-                        break
-                else:
-                    raise RuntimeError(
-                        "on_full='block' made no progress against a full"
-                        " queue — the workload is wedged")
-        req = self.workload.make_request(self._next_rid, payload, **opts)
-        rid = self._next_rid
-        self._next_rid += 1
-        if deadline_s is not None:
-            req.deadline = time.monotonic() + deadline_s
-        if self._queue_full():           # only reachable under "shed"
-            req.failure = FailureInfo(rid, "shed",
-                                      detail=f"queue at cap {self.queue_cap}")
-            req.done = True
-            self._finish(req)           # shed work is terminal, never
-            self.shed += 1              # journaled as an admit
+        with span(ENGINE_SUBMIT):
+            if deadline_s is not None and deadline_s <= 0:
+                raise ValueError(f"deadline_s must be > 0, got {deadline_s}")
+            if self._queue_full():
+                if self.on_full == "raise":
+                    raise QueueFull(
+                        f"queue at cap {self.queue_cap}; submit rejected"
+                        " (on_full='raise')")
+                if self.on_full == "block":
+                    for _ in range(100_000):
+                        self.step()
+                        if not self._queue_full():
+                            break
+                    else:
+                        raise RuntimeError(
+                            "on_full='block' made no progress against a"
+                            " full queue — the workload is wedged")
+            req = self.workload.make_request(self._next_rid, payload, **opts)
+            rid = self._next_rid
+            self._next_rid += 1
+            if deadline_s is not None:
+                req.deadline = time.monotonic() + deadline_s
+            if self._queue_full():           # only reachable under "shed"
+                req.failure = FailureInfo(
+                    rid, "shed", detail=f"queue at cap {self.queue_cap}")
+                req.done = True
+                self._finish(req)           # shed work is terminal, never
+                self.shed += 1              # journaled as an admit
+                return rid
+            if self.journal is not None:
+                # write-ahead: the admit is durable BEFORE the request can
+                # be served, so a crash between here and retire re-serves it
+                store = getattr(self.workload, "store", None)
+                self.journal.admit(
+                    rid, payload, deadline_s=deadline_s,
+                    dict_version=None if store is None else store.version,
+                    opts=opts)
+            self.queue.append(req)
             return rid
-        if self.journal is not None:
-            # write-ahead: the admit is durable BEFORE the request can
-            # be served, so a crash between here and retire re-serves it
-            store = getattr(self.workload, "store", None)
-            self.journal.admit(
-                rid, payload, deadline_s=deadline_s,
-                dict_version=None if store is None else store.version,
-                opts=opts)
-        self.queue.append(req)
-        return rid
 
     def result(self, rid: int):
         return self.finished.get(rid)
@@ -276,29 +280,30 @@ class Engine:
     def step(self):
         """One engine tick: expire deadlines, admit while there is
         capacity, then tick the workload."""
-        now = time.monotonic()
-        if self.queue:
-            still = []
-            for req in self.queue:
-                dl = getattr(req, "deadline", None)
-                if dl is not None and now > dl:
-                    req.failure = FailureInfo(req.rid, "deadline",
-                                              detail="expired while queued")
-                    req.done = True
+        with span(ENGINE_STEP):
+            now = time.monotonic()
+            if self.queue:
+                still = []
+                for req in self.queue:
+                    dl = getattr(req, "deadline", None)
+                    if dl is not None and now > dl:
+                        req.failure = FailureInfo(
+                            req.rid, "deadline", detail="expired while queued")
+                        req.done = True
+                        self._finish(req)
+                    else:
+                        still.append(req)
+                self.queue = still
+            expire = getattr(self.workload, "expire", None)
+            if expire is not None:
+                for req in expire(now):
                     self._finish(req)
-                else:
-                    still.append(req)
-            self.queue = still
-        expire = getattr(self.workload, "expire", None)
-        if expire is not None:
-            for req in expire(now):
+            while self.queue and self.workload.has_capacity():
+                self.workload.admit(self.queue.pop(0))
+            for req in self.workload.tick():
                 self._finish(req)
-        while self.queue and self.workload.has_capacity():
-            self.workload.admit(self.queue.pop(0))
-        for req in self.workload.tick():
-            self._finish(req)
-        if self.policy is not None:
-            self.policy.observe(self)
+            if self.policy is not None:
+                self.policy.observe(self)
 
     def run_until_drained(self, max_ticks: int = 1000, *,
                           on_undrained: str = "raise") -> DrainReport:
@@ -1040,7 +1045,8 @@ class StemmerWorkload:
         n = 0
         waited = False
         while len(self.ring) < self.max_inflight:
-            grp = self._next_group()
+            with span(STEM_COALESCE):
+                grp = self._next_group()
             if grp is None:
                 if self._requeue and not self.ring and not waited:
                     # every retryable group is backing off and nothing
@@ -1105,8 +1111,6 @@ class StemmerWorkload:
     def _dispatch_group(self, grp: RetryGroup) -> int:
         """Launch one group; returns 1 on success, 0 when the failure
         was absorbed into the retry machinery."""
-        from repro.kernels import ops  # lazy: keep engine import light
-
         if self.injector is not None:
             try:
                 self.injector.on_dispatch(
@@ -1141,61 +1145,36 @@ class StemmerWorkload:
             handle = self._degraded_handle(dv)
         use_persistent = self.persistent and not grp.via_megabatch
         slot = self._free_slots.pop()
-        tile = self._staging[slot]
-        placed, fill = [], 0
-        for req, r0, take in grp.segments:
-            tile[fill:fill + take] = req.words[r0:r0 + take]
-            placed.append((req, r0, fill, take))
-            fill += take
-        rows = self._bucket_rows(fill)
-        tile[fill:rows] = 0             # padded words must stay empty
-        flags = checksums = None
-        # with_checksum fuses the per-tile integrity row into the
-        # launch's own jit scope (verified against a host recompute at
-        # retire) — fault tolerance costs no extra XLA dispatch
-        cs = self.checksum
-        try:
-            if self._mesh is not None:
-                out = ops.extract_roots_sharded(
-                    jnp.asarray(tile[:rows]), handle, self._mesh,
-                    infix=self.infix, match=self.match, block_b=self.block_b,
-                    dict_block_r=self.dict_block_r,
-                    num_buffers=self.num_buffers, skip_index=self.skip_index,
-                    with_checksum=cs, interpret=self.interpret)
-                roots, sources = out[0], out[1]
-            elif use_persistent:
-                out = ops.extract_roots_persistent(
-                    jnp.asarray(tile[:rows]), handle, infix=self.infix,
-                    match=self.match, block_b=self.block_b,
-                    dict_block_r=self.dict_block_r,
-                    num_buffers=self.num_buffers, skip_index=self.skip_index,
-                    version_slot=dv.version, with_checksum=cs,
-                    interpret=self.interpret)
-                roots, sources, flags = out[0], out[1], out[2]
-            else:
-                out = ops.extract_roots_fused(
-                    jnp.asarray(tile[:rows]), handle, infix=self.infix,
-                    match=self.match, block_b=self.block_b,
-                    dict_block_r=self.dict_block_r,
-                    num_buffers=self.num_buffers, skip_index=self.skip_index,
-                    with_checksum=cs, interpret=self.interpret)
-                roots, sources = out[0], out[1]
-            if cs:
-                checksums = out[-1]
-        except BaseException as e:
-            # a failed launch must not wedge the engine: return the slot
-            # and route the group through the retry machinery (strict
-            # mode re-raises with the words unclaimed). A launch that
-            # failed to trace, lower or compile fails the same way on
-            # every attempt: it propagates with the words unclaimed
-            # instead of being retried into quarantine.
-            self._free_slots.append(slot)
-            if isinstance(e, (KeyboardInterrupt, SystemExit)):
-                raise
-            if _deterministic_launch_error(e):
-                self._unclaim(grp)
-                raise
-            return self._launch_failed(grp, e)
+        with span(STEM_STAGE):
+            tile = self._staging[slot]
+            placed, fill = [], 0
+            for req, r0, take in grp.segments:
+                tile[fill:fill + take] = req.words[r0:r0 + take]
+                placed.append((req, r0, fill, take))
+                fill += take
+            rows = self._bucket_rows(fill)
+            tile[fill:rows] = 0         # padded words must stay empty
+        with span(STEM_LAUNCH):
+            try:
+                roots, sources, flags, checksums = self._launch(
+                    tile[:rows], handle, dv.version, use_persistent)
+            except BaseException as e:
+                # a failed launch must not wedge the engine: return the
+                # slot and route the group through the retry machinery
+                # (strict mode re-raises with the words unclaimed). A
+                # launch that failed to trace, lower or compile fails the
+                # same way on every attempt: it propagates with the words
+                # unclaimed instead of being retried into quarantine.
+                self._free_slots.append(slot)
+                if isinstance(e, (KeyboardInterrupt, SystemExit)):
+                    raise
+                if _deterministic_launch_error(e):
+                    self._unclaim(grp)
+                    raise
+                return self._launch_failed(grp, e)
+            for arr in (roots, sources, flags, checksums):
+                if arr is not None:     # start D2H early; retire just reads
+                    arr.copy_to_host_async()
         entry = InflightTile(placed, dv.version, roots, sources, slot,
                              flags, checksums_dev=checksums,
                              retries=grp.retries,
@@ -1205,12 +1184,34 @@ class StemmerWorkload:
             # a wedge is observable only through the completion flags,
             # so the stall site covers persistent launches alone
             entry.stalled = self.injector.on_stall()
-        for arr in (roots, sources, flags, checksums):
-            if arr is not None:         # start D2H early; retire just reads
-                arr.copy_to_host_async()
         self.ring.append(entry)
         self.ticks_launched += 1
         return 1
+
+    def _launch(self, rows, handle, version: int, use_persistent: bool):
+        """One megakernel call on the staged ``rows`` -> device arrays
+        (roots, sources, completion flags or None, checksums or None)."""
+        from repro.kernels import ops  # lazy: keep engine import light
+
+        # with_checksum fuses the per-tile integrity row into the
+        # launch's own jit scope (verified against a host recompute at
+        # retire) — fault tolerance costs no extra XLA dispatch
+        cs = self.checksum
+        kw = dict(infix=self.infix, match=self.match, block_b=self.block_b,
+                  dict_block_r=self.dict_block_r,
+                  num_buffers=self.num_buffers, skip_index=self.skip_index,
+                  with_checksum=cs, interpret=self.interpret)
+        flags = None
+        if self._mesh is not None:
+            out = ops.extract_roots_sharded(jnp.asarray(rows), handle,
+                                            self._mesh, **kw)
+        elif use_persistent:
+            out = ops.extract_roots_persistent(jnp.asarray(rows), handle,
+                                               version_slot=version, **kw)
+            flags = out[2]
+        else:
+            out = ops.extract_roots_fused(jnp.asarray(rows), handle, **kw)
+        return out[0], out[1], flags, out[-1] if cs else None
 
     # -- retire side -------------------------------------------------------
     def _retire_ready(self) -> int:
@@ -1332,27 +1333,31 @@ class StemmerWorkload:
         Returns False when the tile failed checksum verification and was
         re-queued for redispatch instead of scattered.
         """
-        roots = np.asarray(entry.roots_dev)
-        sources = np.asarray(entry.sources_dev)
+        with span(STEM_FETCH):
+            roots = np.asarray(entry.roots_dev)
+            sources = np.asarray(entry.sources_dev)
+            flags = (None if entry.flags_dev is None
+                     else np.asarray(entry.flags_dev))
+            want = (None if entry.checksums_dev is None
+                    else np.asarray(entry.checksums_dev))
         self._free_slots.append(entry.slot)
         if self.injector is not None:
             roots, sources = self.injector.on_retire(roots, sources)
-        if entry.flags_dev is not None:
-            # descriptor-ring integrity: every tile of the persistent
-            # launch must have completed under the version pinned at
-            # dispatch (flag = 1 + version slot; 0 = never processed)
-            flags = np.asarray(entry.flags_dev)
-            if not (flags == 1 + entry.version).all():
-                raise RuntimeError(
-                    "persistent launch retired with bad completion flags:"
-                    f" expected {1 + entry.version}, got {flags.tolist()}")
-        if entry.checksums_dev is not None:
+        # descriptor-ring integrity: every tile of the persistent launch
+        # must have completed under the version pinned at dispatch
+        # (flag = 1 + version slot; 0 = never processed)
+        if flags is not None and not (flags == 1 + entry.version).all():
+            raise RuntimeError(
+                "persistent launch retired with bad completion flags:"
+                f" expected {1 + entry.version}, got {flags.tolist()}")
+        if want is not None:
             from repro.kernels import ops
 
-            want = np.asarray(entry.checksums_dev)
-            got = ops.tile_checksum_host(roots, sources,
-                                         block_b=self.block_b)
-            if not np.array_equal(got, want):
+            with span(STEM_VERIFY):
+                got = ops.tile_checksum_host(roots, sources,
+                                             block_b=self.block_b)
+                ok = np.array_equal(got, want)
+            if not ok:
                 bad = np.nonzero(got != want)[0].tolist()
                 err = RuntimeError(
                     f"retire checksum mismatch on tile(s) {bad} of"
@@ -1368,13 +1373,14 @@ class StemmerWorkload:
                                  via_megabatch=entry.via_megabatch)
                 self._launch_failed(grp, err)
                 return False
-        for req, r0, t0, take in entry.segments:
-            if req.failure is not None:   # expired/cancelled mid-flight
-                continue
-            req.roots[r0:r0 + take] = roots[t0:t0 + take]
-            req.sources[r0:r0 + take] = sources[t0:t0 + take]
-            req.dict_versions[r0:r0 + take] = entry.version
-            req.served += take
+        with span(STEM_SCATTER):
+            for req, r0, t0, take in entry.segments:
+                if req.failure is not None:   # expired/cancelled mid-flight
+                    continue
+                req.roots[r0:r0 + take] = roots[t0:t0 + take]
+                req.sources[r0:r0 + take] = sources[t0:t0 + take]
+                req.dict_versions[r0:r0 + take] = entry.version
+                req.served += take
         return True
 
 

@@ -38,6 +38,7 @@ import numpy as np
 from repro.core import alphabet as ab
 from repro.core import textnorm as tn
 from repro.serve.engine import StemmerWorkload, StemRequest
+from repro.serve.spans import TEXT_FETCH, TEXT_FRONTEND, span
 
 FRONTENDS = ("kernel", "reference", "host")
 
@@ -116,19 +117,21 @@ class TextAnalysisWorkload(StemmerWorkload):
                 raise ValueError(
                     "text workload takes str documents, got"
                     f" {type(d).__name__}")
-        chars, _char_off, byte_off = tn.coalesce_docs(docs)
-        n_bytes = sum(len(d.encode("utf-8")) for d in docs)
-        if self.frontend == "host":
-            words, spans, doc_ids = self._frontend_host(docs)
-        else:
-            words, spans, doc_ids = self._frontend_device(chars, byte_off)
-        n = words.shape[0]
-        return TextRequest(
-            rid, np.ascontiguousarray(words, np.int32),
-            roots=np.zeros((n, 4), np.int32),
-            sources=np.zeros(n, np.int32),
-            dict_versions=np.zeros(n, np.int32),
-            docs=docs, doc_ids=doc_ids, spans=spans, n_bytes=n_bytes)
+        with span(TEXT_FRONTEND):
+            chars, _char_off, byte_off = tn.coalesce_docs(docs)
+            n_bytes = sum(len(d.encode("utf-8")) for d in docs)
+            if self.frontend == "host":
+                words, spans, doc_ids = self._frontend_host(docs)
+            else:
+                words, spans, doc_ids = self._frontend_device(chars,
+                                                              byte_off)
+            n = words.shape[0]
+            return TextRequest(
+                rid, np.ascontiguousarray(words, np.int32),
+                roots=np.zeros((n, 4), np.int32),
+                sources=np.zeros(n, np.int32),
+                dict_versions=np.zeros(n, np.int32),
+                docs=docs, doc_ids=doc_ids, spans=spans, n_bytes=n_bytes)
 
     def _frontend_host(self, docs):
         parts = [tn.analyze_text_py(d) for d in docs]
@@ -154,9 +157,10 @@ class TextAnalysisWorkload(StemmerWorkload):
             words_d, geo = tn.frontend_reference(
                 tile, block_w=self.text_block_w)
             spans_d, nw = geo.spans, geo.n_words
-        n = int(nw)
-        words = np.asarray(words_d)[:n]
-        spans_abs = np.asarray(spans_d)[:n].astype(np.int64)
+        with span(TEXT_FETCH):
+            n = int(nw)
+            words = np.asarray(words_d)[:n]
+            spans_abs = np.asarray(spans_d)[:n].astype(np.int64)
         if byte_off.size:
             # word -> owning doc: the last doc whose byte offset is <=
             # the word's absolute byte start (separators add one byte)
