@@ -46,7 +46,7 @@ STREAMED_KEYS = 1 << 18            # production lexicon: > MAX_RESIDENT_KEYS
 REQUESTS, WORDS_PER_REQUEST, BLOCK_B = 256, 64, 256
 TEXT_DOCS, TEXT_WORDS = 64, 300
 INDEX_CHUNKS, CHUNK_WORDS = 4, 1 << 20
-SERVE_MODES = (("per-tile", {}),
+SERVE_MODES = (("per-tile", {"megabatch_tiles": 1}),
                ("megabatch", {"megabatch_tiles": 4}),
                ("persistent", {"megabatch_tiles": 4, "persistent": True}))
 BAD_EVENTS = ("failure", "retry", "checksum_failure", "degrade")

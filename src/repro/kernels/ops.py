@@ -6,6 +6,7 @@ code that pallas_call lowers for TPU. On TPU backends interpret=False.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 
@@ -51,6 +52,19 @@ def dispatch_count() -> int:
 def _count_dispatches(n: int) -> None:
     global _dispatches
     _dispatches += n
+
+
+@contextlib.contextmanager
+def uncounted_dispatches():
+    """Leave :func:`dispatch_count` as it was across the block: for
+    launches that serve no words (a serving workload compiling its
+    launch shapes ahead of traffic)."""
+    global _dispatches
+    before = _dispatches
+    try:
+        yield
+    finally:
+        _dispatches = before
 
 
 def dict_match(keys: jnp.ndarray, dict_keys: jnp.ndarray, *,

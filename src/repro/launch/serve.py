@@ -20,6 +20,7 @@ from repro.models import params as pm
 from repro.serve import (DegradationPolicy, DictStore, Engine, Journal,
                          LMDecodeWorkload, StemmerWorkload,
                          TextAnalysisWorkload)
+from repro.serve.engine import MEGABATCH_TILES
 
 
 def _engine_kw(args) -> dict:
@@ -257,11 +258,12 @@ def main():
     ap.add_argument("--full-sweep", action="store_true",
                     help="disable the tile-visit skip index (sweep every"
                          " dictionary tile; the skip-off baseline)")
-    ap.add_argument("--megabatch", type=int, default=1,
-                    help="super-tiles coalesced per launch: the grid's"
-                         " batch axis spans the whole megabatch, so one"
-                         " dispatch retires up to this many queue tiles"
-                         " (1 = the per-tile baseline)")
+    ap.add_argument("--megabatch", type=int, default=MEGABATCH_TILES,
+                    help="most super-tiles coalesced per launch: the"
+                         " grid's batch axis spans the whole megabatch, so"
+                         " one dispatch retires up to this many queue tiles"
+                         " (default %(default)s, the workload's own;"
+                         " 1 = the per-tile baseline)")
     ap.add_argument("--persistent", action="store_true",
                     help="persistent serving kernel: ONE launch loops a"
                          " device-side work-descriptor ring over the"

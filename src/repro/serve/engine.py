@@ -645,6 +645,12 @@ class RetryGroup:
     # route (a wedged descriptor ring must not be relaunched into)
 
 
+# Default cap on super-tiles per launch. A launch costs about a
+# millisecond of host time whatever its size, so a deep queue should
+# spread it over many tiles.
+MEGABATCH_TILES = 16
+
+
 class StemmerWorkload:
     """Continuous batching of word-batch requests into megakernel tiles,
     dispatch/retire-pipelined so host coalescing overlaps device compute.
@@ -669,15 +675,22 @@ class StemmerWorkload:
                 submit/step iterations
 
     With ``max_inflight=1`` the pipeline degenerates to the synchronous
-    dispatch-then-retire tick (overlap off); with ``megabatch_tiles=1``
-    (default) each launch is one super-tile, the pre-megabatch contract.
-    A partially filled megabatch launches at the next power-of-two
-    super-tile count (capped at ``megabatch_tiles``), so a trickle-fed
-    queue replays a small bounded set of jit traces instead of one per
-    fill level. Tile inputs are built in a preallocated host staging
-    buffer per ring slot (no per-tick allocation); each launch pins the
-    DictStore version it acquired at dispatch, so hot swaps landing
-    between dispatch and retire stay exact per word. ``data_devices > 1``
+    dispatch-then-retire tick (overlap off). A launch carries as many
+    queued super-tiles as the queue holds, up to ``megabatch_tiles``
+    (default :data:`MEGABATCH_TILES`); ``megabatch_tiles=1`` is the
+    per-tile contract, one super-tile a launch. Coalesced words that
+    fit in one super-tile launch as one, so a lone short request still
+    launches one super-tile; more launch as the whole megabatch, zero
+    rows padding it, so a ragged queue replays two jit traces instead
+    of one per fill level. The first launch under a lexicon handle of a
+    new shape (or a new launch geometry) first compiles the other of
+    the two on a zero tile, so no later launch compiles while traffic
+    waits; that warm launch counts in neither ``ticks_launched`` nor
+    ``ops.dispatch_count()``. Tile inputs are built in a preallocated
+    host staging buffer per ring slot (no per-tick allocation); each
+    launch pins the DictStore version it acquired at dispatch, so hot
+    swaps landing between dispatch and retire stay exact per word.
+    ``data_devices > 1``
     routes launches through ``ops.extract_roots_sharded``
     (dist.shard_batch), splitting each megabatch across a ("data",)
     mesh. ``persistent=True`` routes launches through
@@ -707,7 +720,8 @@ class StemmerWorkload:
                  match: str = "bsearch", dict_block_r: int = 8,
                  num_buffers: int = 2, skip_index: bool = True,
                  max_inflight: int = 2, data_devices: int = 1,
-                 megabatch_tiles: int = 1, persistent: bool = False,
+                 megabatch_tiles: int = MEGABATCH_TILES,
+                 persistent: bool = False,
                  max_requests: int | None = None,
                  max_retries: int = 2, retry_backoff_s: float = 0.0,
                  launch_timeout_s: float | None = None,
@@ -766,6 +780,7 @@ class StemmerWorkload:
         self.ring: list[InflightTile] = []
         self._requeue: list[RetryGroup] = []
         self.ticks_launched = 0   # megakernel launches (not engine ticks)
+        self._warmed: set = set()  # launch signatures whose buckets compiled
         # fault-path accounting (tests + benchmarks/recovery.py read these)
         self.retries_total = 0    # failed dispatch attempts charged
         self.bisections = 0       # groups split after exhausting retries
@@ -1008,15 +1023,36 @@ class StemmerWorkload:
         return segments
 
     def _bucket_rows(self, fill: int) -> int:
-        """Staging rows to launch for ``fill`` coalesced words: the next
-        power-of-two super-tile count, capped at megabatch_tiles, so a
-        ragged queue replays O(log megabatch_tiles) jit traces rather
-        than one per fill level."""
-        n_super = -(-fill // self.super_b)
-        bucket = 1
-        while bucket < n_super:
-            bucket *= 2
-        return min(bucket, self.megabatch_tiles) * self.super_b
+        """Staging rows to launch for ``fill`` coalesced words: one
+        super-tile if they fit in one, else the whole megabatch. A
+        launch's host cost hardly grows with its rows, while every
+        launch shape costs set-up time to trace, lower and load (PERF.md
+        §6), so a ragged queue replays two jit traces, not one per fill
+        level."""
+        return self.super_b if fill <= self.super_b else self.launch_b
+
+    def _warm(self, handle, version: int, use_persistent: bool,
+              rows: int) -> None:
+        """On the first launch of a signature (launch path, geometry and
+        the handle's pytree shapes), run a zero tile through the other
+        bucket's launch and wait for it, so both buckets are traced,
+        lowered and compiled before traffic needs them. A hot swap to a
+        handle of the same shapes hits the same jit cache entries and
+        warms nothing again."""
+        leaves, tree = jax.tree.flatten(handle)
+        key = (use_persistent, self.data_devices, self.launch_b, tree,
+               tuple((x.shape, x.dtype) for x in leaves))
+        if key in self._warmed:
+            return
+        from repro.kernels import ops
+
+        with ops.uncounted_dispatches():
+            # the launch itself compiles its own bucket
+            for n in {self.super_b, self.launch_b} - {rows}:
+                jax.block_until_ready(self._launch(
+                    np.zeros((n, ab.MAXLEN), np.int32), handle, version,
+                    use_persistent))
+        self._warmed.add(key)
 
     def _next_group(self) -> RetryGroup | None:
         """The next dispatchable group: an eligible retry first (FIFO),
@@ -1156,6 +1192,7 @@ class StemmerWorkload:
             tile[fill:rows] = 0         # padded words must stay empty
         with span(STEM_LAUNCH):
             try:
+                self._warm(handle, dv.version, use_persistent, rows)
                 roots, sources, flags, checksums = self._launch(
                     tile[:rows], handle, dv.version, use_persistent)
             except BaseException as e:

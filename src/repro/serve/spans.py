@@ -27,6 +27,8 @@ TEXT_FETCH = "repro.text.fetch"         # its blocking device->host reads
 STEM_COALESCE = "repro.stem.coalesce"   # pick a due retry or coalesce FIFO
 STEM_STAGE = "repro.stem.stage"         # copy into the staging buffer
 STEM_LAUNCH = "repro.stem.launch"       # megakernel call, D2H copies started
+                                        # (and, once per handle shape, the
+                                        # other bucket's warm launch)
 STEM_FETCH = "repro.stem.fetch"         # D2H reads at retire (may block)
 STEM_VERIFY = "repro.stem.verify"       # host checksum recompute + compare
 STEM_SCATTER = "repro.stem.scatter"     # results back to the requests

@@ -97,7 +97,7 @@ def test_dispatch_fault_mid_ring_bit_identical(dict_and_words, baseline):
     full drain stays bit-identical to the fault-free run."""
     arrays, enc = dict_and_words
     inj = FaultInjector(FaultPlan(specs=(FaultSpec("dispatch", at=1),)))
-    eng, rids = _drain_8(arrays, enc, injector=inj)
+    eng, rids = _drain_8(arrays, enc, injector=inj, megabatch_tiles=1)
     assert inj.fired == [("dispatch", "fail", 1)]
     assert eng.workload.retries_total == 1
     for rid, want in zip(rids, baseline):

@@ -17,7 +17,9 @@ import pytest
 from repro.core import corpus, stemmer
 from repro.kernels import ops
 from repro.kernels import stem_fused as sf
-from repro.serve import DictStore, Engine, StemmerWorkload
+from repro.serve import (DegradationPolicy, DictStore, Engine,
+                         StemmerWorkload, TextAnalysisWorkload)
+from repro.serve.engine import MEGABATCH_TILES
 
 MATCHES = ("bank", "bsearch")
 
@@ -229,6 +231,145 @@ def test_persistent_serve_flags_checked(dicts, enc):
     eng.run_until_drained()  # healthy path: no raise, versions stamped
     req = eng.result(0)
     assert (req.dict_versions == 0).all()
+
+
+# ---------------------------------------------------------------------------
+# the default: launches follow the queue depth, both shapes warmed
+# ---------------------------------------------------------------------------
+class CompileCounter:
+    """XLA compiles and lowerings while ``on``, by jax.monitoring."""
+
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+    def __init__(self):
+        self.on = False
+        self.compiles = self.lowerings = 0
+
+    def __call__(self, event, _secs, **_kw):
+        if self.on:
+            self.compiles += event == self.COMPILE
+            self.lowerings += event == self.LOWER
+
+
+@pytest.fixture
+def compile_counter():
+    import jax
+
+    counter = CompileCounter()
+    jax.monitoring.register_event_duration_secs_listener(counter)
+    yield counter
+    jax.monitoring.unregister_event_duration_listener(counter)
+
+
+def _launch_rows(workload):
+    """Record the staging rows of every launch ``workload`` makes, warm
+    launches included: -> the list it appends to."""
+    rows, real = [], workload._launch
+
+    def launch(tile, *a, **kw):
+        rows.append(tile.shape[0])
+        return real(tile, *a, **kw)
+
+    workload._launch = launch
+    return rows
+
+
+def test_default_deep_queue_launches_at_cap(dicts, enc):
+    """A deep queue under the default workload launches
+    ceil(words / (cap * block_b)) times, bit-identical to one tile a
+    launch."""
+    sizes = (37, 120, 5, 50, 99, 250, 39)       # 600 words
+    eng = Engine(StemmerWorkload(DictStore(dicts), block_b=32))
+    ops.reset_dispatch_count()
+    rids = [eng.submit(enc[a:b]) for a, b in
+            zip(np.cumsum((0,) + sizes[:-1]), np.cumsum(sizes))]
+    assert eng.run_until_drained().drained
+    want = -(-sum(sizes) // (MEGABATCH_TILES * 32))
+    assert eng.workload.megabatch_tiles == MEGABATCH_TILES
+    assert (TextAnalysisWorkload(DictStore(dicts)).megabatch_tiles
+            == MEGABATCH_TILES)
+    assert eng.workload.ticks_launched == ops.dispatch_count() == want
+    ref_eng, ref_rids = _serve(DictStore(dicts), enc, sizes, block_b=32)
+    assert ref_eng.workload.ticks_launched == -(-sum(sizes) // 32)
+    for g, r in zip(_gather(eng, rids), _gather(ref_eng, ref_rids)):
+        np.testing.assert_array_equal(g, r)
+
+
+@pytest.mark.parametrize("n_words", [1, 20, 32])
+def test_lone_request_launches_one_tile(dicts, enc, n_words):
+    """A lone request of one tile or less launches one block_b tile
+    under the default cap."""
+    w = StemmerWorkload(DictStore(dicts), block_b=32)
+    rows = _launch_rows(w)
+    eng = Engine(w)
+    rid = eng.submit(enc[:n_words])
+    assert eng.run_until_drained().drained
+    assert w.ticks_launched == 1 and rows[-1] == 32
+    want_r, want_s = stemmer.stem_batch(jnp.asarray(enc[:n_words]), dicts)
+    np.testing.assert_array_equal(eng.result(rid).roots, np.asarray(want_r))
+    np.testing.assert_array_equal(eng.result(rid).sources,
+                                  np.asarray(want_s))
+
+
+def test_every_bucket_compiled_by_the_first_dispatch(dicts, enc,
+                                                     compile_counter):
+    """After the first dispatch under a handle, a launch at every bucket
+    of the ladder traces, lowers and compiles nothing; more than one
+    tile of words launches the whole megabatch."""
+    w = StemmerWorkload(DictStore(dicts), block_b=32)
+    eng = Engine(w)
+    eng.submit(enc[:5])
+    assert eng.run_until_drained().drained
+    rows = _launch_rows(w)
+    compile_counter.on = True
+    rids, sizes = [], (32, 33, 300, 512)
+    for n in sizes:                   # one request a launch
+        rids.append(eng.submit(enc[:n]))
+        assert eng.run_until_drained().drained
+    compile_counter.on = False
+    assert rows == [32, 512, 512, 512]
+    assert (compile_counter.compiles, compile_counter.lowerings) == (0, 0)
+    for rid, n in zip(rids, sizes):
+        want_r, _ = stemmer.stem_batch(jnp.asarray(enc[:n]), dicts)
+        np.testing.assert_array_equal(eng.result(rid).roots,
+                                      np.asarray(want_r))
+
+
+def test_warm_launches_are_not_counted(dicts, enc):
+    """The warm launch of the first dispatch leaves ticks_launched and
+    ops.dispatch_count() as they were; a hot swap to a handle of the
+    same shapes warms nothing again."""
+    store = DictStore(dicts)
+    w = StemmerWorkload(store, block_b=32)
+    rows = _launch_rows(w)
+    eng = Engine(w)
+    ops.reset_dispatch_count()
+    eng.submit(enc[:40])                        # two tiles: the cap
+    assert eng.run_until_drained().drained
+    assert rows == [32, 512]                    # the warm, then the launch
+    assert w.ticks_launched == ops.dispatch_count() == 1
+
+    shifted = stemmer.RootDictArrays(tri=dicts.tri + 1, quad=dicts.quad + 1,
+                                     bi=dicts.bi + 1)   # same shapes
+    store.publish(shifted)
+    rows.clear()
+    rid = eng.submit(enc[:40])
+    assert eng.run_until_drained().drained
+    assert rows == [512]
+    assert w.ticks_launched == ops.dispatch_count() == 2
+    want_r, _ = stemmer.stem_batch(jnp.asarray(enc[:40]), shifted)
+    np.testing.assert_array_equal(eng.result(rid).roots, np.asarray(want_r))
+    assert (eng.result(rid).dict_versions == 1).all()
+
+
+def test_default_ladder_keeps_per_tile_rung(dicts):
+    """The degradation ladder of a default workload steps down from the
+    capped megabatch to one tile a launch."""
+    pol = DegradationPolicy()
+    Engine(StemmerWorkload(DictStore(dicts)), policy=pol)
+    assert [(r.label, r.megabatch_tiles) for r in pol.rungs[:2]] == [
+        (f"megabatch x{MEGABATCH_TILES}", MEGABATCH_TILES), ("per-tile", 1)]
 
 
 # ---------------------------------------------------------------------------

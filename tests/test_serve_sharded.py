@@ -69,7 +69,7 @@ SCRIPT = textwrap.dedent("""
     # --- sharded serving: super-tile coalescing through the ring ------
     store = DictStore(arrays)
     eng = Engine(StemmerWorkload(store, block_b=16, data_devices=4,
-                                 max_inflight=2))
+                                 max_inflight=2, megabatch_tiles=1))
     sizes = (37, 64, 5, 50)          # 156 words, super_b=64 -> 3 launches
     off, rids = 0, []
     for n in sizes:
@@ -91,7 +91,7 @@ SCRIPT = textwrap.dedent("""
     store = DictStore(arrays)
     grown = corpus.grow_root_arrays(arrays, 2048, seed=7)
     eng = Engine(StemmerWorkload(store, block_b=16, data_devices=4,
-                                 max_inflight=2))
+                                 max_inflight=2, megabatch_tiles=1))
     rids = [eng.submit(enc[i * 32:(i + 1) * 32]) for i in range(6)]
     eng.step()                       # 2 super-tiles (128 words) in flight
     assert eng.workload.ticks_launched == 2

@@ -25,11 +25,11 @@ def dict_and_words():
 
 
 def _serve(store, enc, sizes, *, block_b=32, steps_before_swap=None,
-           swap_to=None, max_inflight=2, max_requests=None):
+           swap_to=None, max_inflight=2, max_requests=None, **kw):
     """Submit word batches of the given sizes, optionally hot-swap, drain."""
     eng = Engine(StemmerWorkload(store, block_b=block_b,
                                  max_inflight=max_inflight,
-                                 max_requests=max_requests))
+                                 max_requests=max_requests, **kw))
     off, rids = 0, []
     for n in sizes:
         rids.append(eng.submit(enc[off:off + n]))
@@ -75,7 +75,7 @@ def test_serve_coalesces_across_requests(dict_and_words):
     arrays, enc = dict_and_words
     store = DictStore(arrays)
     sizes = (10,) * 13  # 130 words
-    eng, rids, rep = _serve(store, enc, sizes, block_b=32)
+    eng, rids, rep = _serve(store, enc, sizes, block_b=32, megabatch_tiles=1)
     assert eng.workload.ticks_launched == -(-130 // 32)  # 5 tiles
     assert all(eng.result(r).done for r in rids)
 
@@ -120,7 +120,8 @@ def test_tick_dispatches_until_ring_full(dict_and_words):
     coalescing bug)."""
     arrays, enc = dict_and_words
     store = DictStore(arrays)
-    eng = Engine(StemmerWorkload(store, block_b=16, max_inflight=4))
+    eng = Engine(StemmerWorkload(store, block_b=16, max_inflight=4,
+                                 megabatch_tiles=1))
     for i in range(10):
         eng.submit(enc[i * 16:(i + 1) * 16])   # 10 tiles pending
     eng.step()
@@ -137,7 +138,8 @@ def test_ticks_to_drain_shrink_with_ring_depth(dict_and_words):
     store = DictStore(arrays)
     ticks, launches = {}, {}
     for depth in (1, 4):
-        eng = Engine(StemmerWorkload(store, block_b=16, max_inflight=depth))
+        eng = Engine(StemmerWorkload(store, block_b=16, max_inflight=depth,
+                                     megabatch_tiles=1))
         for i in range(10):                    # 160 words -> 10 tiles
             eng.submit(enc[i * 16:(i + 1) * 16])
         rep = eng.run_until_drained()
@@ -303,7 +305,8 @@ def test_hot_swap_mid_stream_bit_identical(dict_and_words):
     grown = corpus.grow_root_arrays(arrays, 2048, seed=7)
     sizes = (30, 30, 30, 30, 30)
     eng, rids, _ = _serve(store, enc, sizes, block_b=32,
-                          steps_before_swap=2, swap_to=grown)
+                          steps_before_swap=2, swap_to=grown,
+                          megabatch_tiles=1)
 
     versions = np.concatenate([eng.result(r).dict_versions for r in rids])
     assert set(versions.tolist()) == {0, 1}  # swap landed mid-stream
@@ -351,7 +354,8 @@ def test_swap_while_tile_in_flight_pins_dispatch_version(dict_and_words):
     arrays, enc = dict_and_words
     store = DictStore(arrays)
     grown = corpus.grow_root_arrays(arrays, 2048, seed=9)
-    eng = Engine(StemmerWorkload(store, block_b=16, max_inflight=4))
+    eng = Engine(StemmerWorkload(store, block_b=16, max_inflight=4,
+                                 megabatch_tiles=1))
     rids = [eng.submit(enc[i * 16:(i + 1) * 16]) for i in range(8)]
     eng.step()                      # fills the ring: 4 tiles in flight
     w = eng.workload

@@ -110,7 +110,7 @@ def test_text_hot_swap_mid_stream(arrays, docs):
     grown = corpus.grow_root_arrays(arrays, 2048, seed=7)
     store = DictStore(arrays)
     eng = Engine(TextAnalysisWorkload(store, block_b=16, char_block=256,
-                                      max_inflight=2))
+                                      max_inflight=2, megabatch_tiles=1))
     rids = [eng.submit([d]) for d in docs]
     for _ in range(2):
         eng.step()

@@ -98,8 +98,9 @@ def _same_results(a, b):
 
 def test_stemmer_spans(tmp_path, lexicon, word_batches):
     store = DictStore(lexicon)
-    plain = _serve(StemmerWorkload(store, block_b=32), word_batches)
-    work = StemmerWorkload(store, block_b=32)
+    plain = _serve(StemmerWorkload(store, block_b=32, megabatch_tiles=1),
+                   word_batches)
+    work = StemmerWorkload(store, block_b=32, megabatch_tiles=1)
     traced, found = _profiled(tmp_path, lambda: _serve(work, word_batches))
     _same_results(plain, traced)
     assert {s[0] for s in found} == {spans.ENGINE_SUBMIT, spans.ENGINE_STEP,
