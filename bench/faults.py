@@ -6,10 +6,11 @@ the program, never a private method; a run with any of them must come
 out ``correct: false``. Only the tests plant them: no run of
 ``bench/run.py`` does.
 
-  answer     the lexicon the program gets differs from the reference's
-             in one key, that of the most frequent root: the kernel
-             produces altered roots for its words, with checksums that
-             agree with them (an answer altered where it is produced)
+  answer     the lexicon the program gets (the engine's or the index
+             builder's) differs from the reference's in one key, that of
+             the most frequent root: the kernel produces altered roots
+             for its words, with checksums that agree with them (an
+             answer altered where it is produced)
   frontend   the text front end (``ops.text_to_words``) returns one
              altered word row per launch
   unchanged  every request the engine finishes keeps its answers as
@@ -45,7 +46,9 @@ def _answer():
             return orig(self, config, tables, **kw)
         return init
 
-    return _patch(drive.EngineSystem, "__init__", make)
+    undo = [_patch(cls, "__init__", make)
+            for cls in (drive.EngineSystem, drive.IndexSystem)]
+    return lambda: [u() for u in undo]
 
 
 def _frontend():

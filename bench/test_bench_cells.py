@@ -28,14 +28,6 @@ TINY = {
 }
 
 
-# every mix of bench/traffic/, as a cell, whether or not BENCHMARK.json
-# measures it yet
-CELLS = {"newswire.docs": ("newswire", "docs"),
-         "newswire.queries": ("newswire", "queries"),
-         "quran.words": ("quran", "words"),
-         "newswire-x4.index": ("newswire-x4", "index")}
-
-
 def _tiny_root(tmp: Path) -> Path:
     """A checkout holding a copy of ``bench/`` with every mix cut to a
     test's size and every cell on one device."""
@@ -49,18 +41,6 @@ def _tiny_root(tmp: Path) -> Path:
         mix["payload"] = payload
         path.write_text(json.dumps(mix))
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    have = {c["name"] for c in bench["workloads"]}
-    bench["workloads"] += [{"name": n, "config": c, "traffic": t, "why": "test"}
-                           for n, (c, t) in CELLS.items() if n not in have]
-    have = {c["name"] for c in bench["configs"]}
-    bench["configs"] += [{"name": c, "file": f"bench/configs/{c}.json"}
-                         for c, _t in CELLS.values() if c not in have]
-    measured = {m["name"] for m in bench["end_to_end"]}
-    bench["end_to_end"] += [
-        {"name": m, "unit": "u", "workloads": [n]}
-        for m, n in (("p95_ms", "newswire.queries"),
-                     ("index_words_per_s", "newswire-x4.index"))
-        if m not in measured]
     for cell in bench["workloads"]:
         cell["chips"] = 1
     for entry in bench["configs"]:
@@ -119,9 +99,13 @@ def _run(harness, root, capsys, cell, fault="none", trace=0):
     ("newswire.docs", "frontend", False),
     ("newswire.queries", "none", True),
     ("newswire.queries", "control", False),
+    ("newswire.queries", "answer", False),
+    ("newswire.queries", "frontend", False),
+    ("newswire.queries", "unchanged", False),
     ("newswire-x4.index", "none", True),
     ("newswire-x4.index", "control", False),
     ("newswire-x4.index", "exchange", False),
+    ("newswire-x4.index", "answer", False),
 ])
 def test_correct_catches_control_and_faults(harness, tiny, capsys, cell,
                                             fault, correct):
@@ -134,6 +118,49 @@ def test_correct_catches_control_and_faults(harness, tiny, capsys, cell,
     if correct:
         assert all(v["value"] <= v["limit"] for v in res["check"].values())
         assert "setup_s" in res["metrics"]
+
+
+def _committed() -> dict:
+    return json.loads((ROOT / "BENCHMARK.json").read_text())
+
+
+@pytest.mark.parametrize("cell, metrics", [
+    ("newswire.docs", {"words_per_s", "setup_s"}),
+    ("quran.words", {"words_per_s", "setup_s"}),
+    ("newswire.queries", {"p95_ms", "setup_s"}),
+    ("newswire-x4.index", {"index_words_per_s", "setup_s"}),
+])
+def test_committed_cell_resolves(cell, metrics):
+    """Each cell of the committed BENCHMARK.json resolves to its own
+    end-to-end metrics, and each of its per-layer metrics moves one of
+    them through a reader that exists."""
+    from bench import spec
+
+    c = spec.resolve(ROOT, cell)
+    assert {m["name"] for m in c.end_to_end} == metrics
+    assert c.per_layer
+    for m in c.per_layer:
+        assert m["moves"] in metrics, m["name"]
+        assert (ROOT / "bench" / "readers" / f"{m['reader']}.py").is_file()
+    for m in c.end_to_end:
+        assert (ROOT / "bench" / "measures" / f"{m['name']}.py").is_file()
+
+
+def test_every_per_layer_entry_has_its_layer_file():
+    """A per-layer entry and its layer file agree on the layer, unit,
+    metric moved and cells."""
+    for m in _committed()["per_layer"]:
+        path = ROOT / "bench" / "layers" / f"{m['name']}.json"
+        assert path.is_file(), m["name"]
+        layer = json.loads(path.read_text())
+        for key in ("layer", "unit", "moves", "workloads"):
+            assert layer.get(key) == m.get(key), (m["name"], key)
+
+
+def test_at_most_half_the_cells_take_four_chips():
+    cells = _committed()["workloads"]
+    assert {c["chips"] for c in cells} <= {1, 4}
+    assert sum(c["chips"] == 4 for c in cells) <= len(cells) // 2
 
 
 def test_a_new_cell_is_files_and_entries(harness, tmp_path, capsys):
