@@ -13,9 +13,30 @@ guarantee that the configurations state broken, to show that the
 comparison fails it: every word served exactly once (each request's
 answers shifted by one word), and every corpus word indexed exactly once
 (the last device's share of every chunk left out of the merge).
+
+Durability, where a configuration states a journal: every request the
+client got an answer for is read back from the journal's file, parsed
+here by the record format the program documents (``<sha16>
+<canonical-json>\n``, ``sha16`` the first 16 hex digits of the sha256
+of the JSON's UTF-8 bytes), with no function of the program. Its admit
+record has to end within the file's size as the harness read it when
+``submit`` returned: written to the operating system before the
+acknowledgement, so it survives the death of the process. And the
+window's records have to be met by at least one fsync of the file for
+every ``fsync_every`` of them, counted by the harness around
+``os.fsync``. The control reads the file with the admit record of the
+window's first finished request left out.
+
+What a run cannot show: whether an fsync reached stable storage. That
+depends on the filesystem and the disk under it, which only a loss of
+power tests; the run refuses a journal on tmpfs or ramfs, where an
+fsync does nothing, and logs the filesystem's type.
 """
 from __future__ import annotations
 
+import base64
+import hashlib
+import json
 from dataclasses import dataclass
 
 import numpy as np
@@ -141,3 +162,93 @@ def index_numbers(results: list, chunks: list, roots, tables: dict, *,
                                np.column_stack([want[1], want[2]]))
     return [Number("index_counts", counts, 0),
             Number("index_postings", postings, 0)]
+
+
+def _sha16(data: bytes) -> str:
+    return hashlib.sha256(data).hexdigest()[:16]
+
+
+def read_journal(path) -> tuple[list[tuple[int, dict]], int]:
+    """-> (each record of a journal file in file order, with the offset
+    at which its line ends; the lines whose framing or checksum fails, an
+    unterminated last line among them)."""
+    with open(path, "rb") as f:
+        lines = f.read().split(b"\n")
+    torn = int(lines.pop() != b"")
+    records, end = [], 0
+    for line in lines:
+        end += len(line) + 1
+        sha, sep, body = line.partition(b" ")
+        if not sep or sha != _sha16(body).encode():
+            torn += 1
+            continue
+        try:
+            rec = json.loads(body.decode("utf-8"))
+        except ValueError:
+            torn += 1
+            continue
+        if isinstance(rec, dict):
+            records.append((end, rec))
+        else:
+            torn += 1
+    return records, torn
+
+
+def _sent(payload, body) -> bool:
+    """Whether an admit record's ``payload`` is the body the client sent:
+    the text itself, or the word rows' bytes, dtype and shape."""
+    if not isinstance(payload, dict):
+        return False
+    if isinstance(body, str):
+        return payload.get("t") == "str" and payload.get("s") == body
+    a = np.ascontiguousarray(body)
+    try:
+        raw = base64.b64decode(payload.get("b64", ""), validate=True)
+    except ValueError:
+        return False
+    return (payload.get("t") == "nd" and payload.get("dtype") == str(a.dtype)
+            and payload.get("shape") == list(a.shape) and raw == a.tobytes())
+
+
+def journal_numbers(system, served, bodies: list, *, fsync_every: int,
+                    control: bool = False) -> list[Number]:
+    """Every finished request of the window that did not fail, read back
+    from the system's journal file: exactly one admit record holding the
+    body sent, before exactly one retire record whose digest is that of
+    the answer the client got (sha16 of its roots' bytes, then its
+    sources' bytes); the admit in the file when ``submit`` returned; and
+    an fsync of the file for every ``fsync_every`` records the window
+    wrote."""
+    records, torn = read_journal(system.journal.path)
+    ok = [(i, rid) for i, rid, _d, _t in served.done
+          if system.result(rid).failure is None]
+    if control and ok:
+        first = ok[0][1]
+        records = [(end, r) for end, r in records
+                   if not (r.get("kind") == "admit" and r.get("rid") == first)]
+    admits: dict[int, list] = {}
+    retires: dict[int, list] = {}
+    for end, rec in records:
+        by_kind = {"admit": admits, "retire": retires}.get(rec.get("kind"))
+        if by_kind is not None and isinstance(rec.get("rid"), int):
+            by_kind.setdefault(rec["rid"], []).append((end, rec))
+    bad_admits = bad_retires = unflushed = 0
+    for i, rid in ok:
+        req = system.result(rid)
+        adm, ret = admits.get(rid, []), retires.get(rid, [])
+        bad_admits += not (len(adm) == 1 and _sent(adm[0][1].get("payload"),
+                                                   bodies[i])
+                           and all(adm[0][0] < end for end, _r in ret))
+        unflushed += len(adm) == 1 and adm[0][0] > system.acked[rid]
+        digest = _sha16(np.ascontiguousarray(req.roots).tobytes()
+                        + np.ascontiguousarray(req.sources).tobytes())
+        bad_retires += not (len(ret) == 1
+                            and ret[0][1].get("digest") == digest)
+    lo = served.journal_from
+    written = sum(lo < end <= lo + served.journal_bytes for end, _r in records)
+    return [Number("journal_torn", torn, 0),
+            Number("journal_admits", bad_admits, 0),
+            Number("journal_retires", bad_retires, 0),
+            Number("journal_unflushed", unflushed, 0),
+            Number("journal_fsyncs_short",
+                   max(0, written // fsync_every - served.journal_fsyncs), 0)]

@@ -14,11 +14,18 @@ One general generator serves every mix file. A mix names
 
 and the payload's own sizes. Spans of the benchmark's own
 (``jax.profiler.TraceAnnotation``) mark the calls into the program.
+
+Where the configuration states a journal, the engine serves through the
+program's write-ahead ``Journal`` in a directory the run gives it, and
+the harness watches the file as it grows: its size when each ``submit``
+returns, and the ``os.fsync`` calls made on it.
 """
 from __future__ import annotations
 
+import os
 import time
 from dataclasses import dataclass, field
+from pathlib import Path
 
 import numpy as np
 
@@ -98,13 +105,52 @@ class Served:
     t_end: float = 0.0
     launches: int = 0
     dispatches: int = 0
+    # with a journal: records appended and bytes written in the window,
+    # the file's size when it opened, and the fsyncs made on the file
+    journal_records: int | None = None
+    journal_bytes: int | None = None
+    journal_from: int | None = None
+    journal_fsyncs: int | None = None
+
+
+class JournalWatch:
+    """The harness's own view of a journal file while the program writes
+    it: its size on the operating system, read through a descriptor of
+    the harness's own, and the ``os.fsync`` calls made on it, counted by
+    a wrapper installed until ``close``."""
+
+    def __init__(self, path: Path):
+        self._fd = os.open(path, os.O_RDONLY)
+        self._stat = os.fstat(self._fd)
+        self.fsyncs = 0
+        self._fsync = os.fsync
+        os.fsync = self._counted
+
+    def _counted(self, fd) -> None:
+        self._fsync(fd)
+        fd = fd if isinstance(fd, int) else fd.fileno()
+        self.fsyncs += os.path.samestat(os.fstat(fd), self._stat)
+
+    def size(self) -> int:
+        return os.fstat(self._fd).st_size
+
+    def close(self) -> None:
+        if os.fsync == self._counted:
+            os.fsync = self._fsync
+        os.close(self._fd)
 
 
 class EngineSystem:
     """``Engine`` over ``TextAnalysisWorkload`` or ``StemmerWorkload`` with
-    the program's default launch settings."""
+    the program's default launch settings; given ``journal_dir``, with
+    the program's ``Journal`` there, fsynced every ``fsync_every``
+    records."""
 
-    def __init__(self, config: dict, tables: dict, text: bool):
+    JOURNAL_FILE = "journal.log"
+
+    def __init__(self, config: dict, tables: dict, text: bool,
+                 journal_dir: Path | None = None,
+                 fsync_every: int | None = None):
         import jax.numpy as jnp
 
         from repro.core.stemmer import RootDictArrays
@@ -116,7 +162,15 @@ class EngineSystem:
         kw = dict(infix=config["infix"], data_devices=config["chips"])
         self.workload = (TextAnalysisWorkload(store, frontend="kernel", **kw)
                          if text else StemmerWorkload(store, **kw))
-        self.engine = Engine(self.workload)
+        self.journal = self.watch = None
+        self.acked: dict[int, int] = {}  # rid -> file size when acknowledged
+        if journal_dir is not None:
+            from repro.serve.journal import Journal
+
+            path = Path(journal_dir) / self.JOURNAL_FILE
+            self.journal = Journal(path, fsync_every=fsync_every)
+            self.watch = JournalWatch(path)
+        self.engine = Engine(self.workload, journal=self.journal)
         self.finished = self.engine.finished
 
     def drain(self, bodies: list) -> None:
@@ -135,15 +189,46 @@ class EngineSystem:
         return self.finished[rid]
 
     def release(self) -> None:
-        """Drop the engine, its workload and the lexicon on the device;
-        the finished requests (host arrays) stay for the check."""
+        """Drop the engine, its workload and the lexicon on the device,
+        and close the journal (which fsyncs it); the finished requests
+        (host arrays) and the journal's file stay for the check."""
         self.finished = self.engine.finished
         self.engine = self.workload = None
+        if self.journal is not None:
+            self.journal.close()
+            self.watch.close()
+
+    def _submit(self, body) -> int:
+        """``Engine.submit``; with a journal, the file's size as it returns
+        (what the acknowledgement has made durable) is kept by rid."""
+        with span("submit"):
+            rid = self.engine.submit(body)
+        if self.watch is not None:
+            self.acked[rid] = self.watch.size()
+        return rid
 
     def _counters(self) -> tuple[int, int]:
         from repro.kernels import ops
 
         return self.workload.ticks_launched, ops.dispatch_count()
+
+    def _journal_state(self) -> tuple[int, int, int] | None:
+        """(records appended, the file's size, fsyncs), None without a
+        journal."""
+        if self.journal is None:
+            return None
+        return self.journal.appended, self.watch.size(), self.watch.fsyncs
+
+    def _close_window(self, out: Served, launches0: int, disp0: int,
+                      journal0: tuple[int, int, int] | None) -> None:
+        out.t_end = now()
+        launches1, disp1 = self._counters()
+        out.launches, out.dispatches = launches1 - launches0, disp1 - disp0
+        if journal0 is not None:
+            end = self._journal_state()
+            out.journal_records, out.journal_bytes, out.journal_fsyncs = (
+                b - a for a, b in zip(journal0, end))
+            out.journal_from = journal0[1]
 
     def closed(self, bodies: list, clients: int, seconds: float) -> Served:
         """``clients`` callers, each sending its next body (cycling
@@ -153,14 +238,14 @@ class EngineSystem:
         outstanding: dict[int, tuple[int, float]] = {}
         sent = 0
         launches0, disp0 = self._counters()
+        journal0 = self._journal_state()
         out.t0 = t = now()
         stop = out.t0 + seconds
 
         def send(t):
             nonlocal sent
             i = sent % len(bodies)
-            with span("submit"):
-                outstanding[eng.submit(bodies[i])] = (i, t)
+            outstanding[self._submit(bodies[i])] = (i, t)
             sent += 1
 
         for _ in range(clients):
@@ -174,9 +259,7 @@ class EngineSystem:
                 out.done.append((i, rid, t_sent, t))
                 if t < stop:
                     send(t)
-        out.t_end = now()
-        launches1, disp1 = self._counters()
-        out.launches, out.dispatches = launches1 - launches0, disp1 - disp0
+        self._close_window(out, launches0, disp0, journal0)
         return out
 
     def open(self, bodies: list, seconds: float, grace_s: float) -> Served:
@@ -187,6 +270,7 @@ class EngineSystem:
         due = np.cumsum(gen.arrival_gaps(len(bodies), seconds))
         outstanding: dict[int, tuple[int, float]] = {}
         launches0, disp0 = self._counters()
+        journal0 = self._journal_state()
         out.t0 = now()
         due = out.t0 + due
         give_up = out.t0 + seconds + grace_s
@@ -197,8 +281,7 @@ class EngineSystem:
                 break
             while k < len(bodies) and due[k] <= t:
                 out.lateness.append(t - due[k])
-                with span("submit"):
-                    outstanding[eng.submit(bodies[k])] = (k, due[k])
+                outstanding[self._submit(bodies[k])] = (k, due[k])
                 k += 1
                 t = now()
             if outstanding:
@@ -211,10 +294,8 @@ class EngineSystem:
             elif k < len(bodies):
                 with span("wait"):
                     time.sleep(max(0.0, due[k] - now()))
-        out.t_end = now()
+        self._close_window(out, launches0, disp0, journal0)
         out.missing = [(i, rid, d) for rid, (i, d) in outstanding.items()]
-        launches1, disp1 = self._counters()
-        out.launches, out.dispatches = launches1 - launches0, disp1 - disp0
         return out
 
 

@@ -17,8 +17,17 @@ out ``correct: false``. Only the tests plant them: no run of
              they were allocated (a step that leaves the state unchanged)
   exchange   the last quarter of every corpus chunk never reaches the
              index (one device's share of the exchange left out)
+  journal    every 50th admit to the write-ahead journal
+             (``Journal.admit``) writes nothing and returns as if it
+             had written (an acknowledged request that is not durable)
+  journal_late  each admit is held back and written just before its
+             request's retire (acknowledged before it is durable)
+  journal_fsync  the journal fsyncs every 1000th record, not every
+             ``fsync_every``-th (a batch longer than the one stated)
 """
 from __future__ import annotations
+
+import itertools
 
 import numpy as np
 
@@ -93,8 +102,56 @@ def _exchange():
     return _patch(ops, "build_root_index", make)
 
 
+def _journal():
+    from repro.serve.journal import Journal
+
+    def make(orig):
+        count = itertools.count(1)
+
+        def admit(self, *a, **kw):
+            if next(count) % 50:
+                return orig(self, *a, **kw)
+            return None
+        return admit
+
+    return _patch(Journal, "admit", make)
+
+
+def _journal_late():
+    from repro.serve.journal import Journal
+
+    held: dict = {}
+
+    def make_admit(orig):
+        def admit(self, rid, *a, **kw):
+            held[rid] = lambda: orig(self, rid, *a, **kw)
+        return admit
+
+    def make_retire(orig):
+        def retire(self, req):
+            held.pop(req.rid, lambda: None)()
+            return orig(self, req)
+        return retire
+
+    undo = [_patch(Journal, "admit", make_admit),
+            _patch(Journal, "retire", make_retire)]
+    return lambda: [u() for u in undo]
+
+
+def _journal_fsync():
+    from repro.serve.journal import Journal
+
+    def make(orig):
+        def init(self, path, **kw):
+            orig(self, path, **{**kw, "fsync_every": 1000})
+        return init
+
+    return _patch(Journal, "__init__", make)
+
+
 PLANT = {"answer": _answer, "frontend": _frontend, "unchanged": _unchanged,
-         "exchange": _exchange}
+         "exchange": _exchange, "journal": _journal,
+         "journal_late": _journal_late, "journal_fsync": _journal_fsync}
 
 
 def plant(name: str):

@@ -17,6 +17,14 @@ and last ``check``, each compared number with its limit. The run fails,
 printing no result, where JAX finds no TPU, fewer chips than the cell
 asks for, or a device kind that ``bench/peaks.json`` does not list.
 
+Where the configuration states a ``journal``, the engine writes its
+write-ahead journal to a fresh directory under ``.bench_journal/`` in the
+checkout (on the checkout's disk: the temporary directory may be a
+tmpfs, where an fsync costs nothing), the run logs that directory's
+filesystem type and fails, printing no result, where it is tmpfs or
+ramfs; the check reads every acknowledged request back from the file,
+and the directory is removed when the run ends, however it ends.
+
 ``--fault control`` (never used by a measured run) puts the reference,
 with one stated guarantee broken, in the program's place; it must come
 out ``correct: false``.
@@ -28,9 +36,11 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import statistics  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -49,6 +59,8 @@ from bench.drive import percentile  # noqa: E402
 
 GRACE_S = 60.0          # an open-loop request unfinished this long after
                         # the window is missing
+JOURNAL_DIR = ".bench_journal"  # under the checkout: a directory a run
+MEMORY_FS = ("tmpfs", "ramfs")  # where an fsync does nothing
 
 
 class NoChip(RuntimeError):
@@ -120,6 +132,44 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
+def filesystem_type(path: Path) -> str:
+    """The type of the filesystem holding ``path``: that of the longest
+    mount point in ``/proc/self/mounts`` that contains it."""
+    path = os.path.realpath(path)
+    best, fstype = "", "unknown"
+    try:
+        with open("/proc/self/mounts", encoding="utf-8") as f:
+            mounts = [line.split() for line in f]
+    except OSError:
+        return fstype
+    for fields in mounts:
+        if len(fields) < 3:
+            continue
+        # spaces, tabs and backslashes in a mount point come escaped: \040
+        mnt = re.sub(r"\\([0-7]{3})", lambda m: chr(int(m.group(1), 8)),
+                     fields[1])
+        inside = path == mnt or path.startswith(mnt.rstrip("/") + "/")
+        if inside and len(mnt) >= len(best):    # a later mount hides one
+            best, fstype = mnt, fields[2]       # at the same point
+    return fstype
+
+
+@contextlib.contextmanager
+def journal_directory(root: Path):
+    """A fresh directory under ``<root>/.bench_journal/``, removed (with
+    the parent, once that is empty) when the block ends, however it
+    ends."""
+    parent = Path(root) / JOURNAL_DIR
+    parent.mkdir(exist_ok=True)
+    try:
+        with tempfile.TemporaryDirectory(prefix=f"{os.getpid()}-",
+                                         dir=parent) as path:
+            yield Path(path)
+    finally:
+        with contextlib.suppress(OSError):
+            parent.rmdir()
+
+
 def run_window(cell, system, bodies, seconds: float, trace_dir: str | None):
     """The measured window (profiled when ``trace_dir`` is given)."""
     import jax
@@ -169,6 +219,9 @@ def counters(cell, system, bodies, served, lex_tables) -> dict:
     if isinstance(bodies[0], str):
         c["chars_frontend"] = sum(len(bodies[i]) for i, *_ in served.done)
         c["words_frontend"] = c["words_served"]
+    if served.journal_records is not None:
+        c.update(journal_records=served.journal_records,
+                 journal_bytes=served.journal_bytes)
     return c
 
 
@@ -202,19 +255,38 @@ def main(argv=None, root: Path = ROOT) -> int:
         log(f"run.py: {e}")
         return 1
     cache = enable_compile_cache()
-    return measure(args, cell, devices, peak, cache)
+    if cell.fsync_every is None:
+        return measure(args, cell, devices, peak, cache)
+    with journal_directory(root) as journal_dir:
+        fs = filesystem_type(journal_dir)
+        log(f"journal in {journal_dir}: filesystem {fs},"
+            f" fsync every {cell.fsync_every} records")
+        if fs in MEMORY_FS:
+            log(f"run.py: the journal's filesystem is {fs}: an fsync there"
+                " makes nothing durable")
+            return 1
+        return measure(args, cell, devices, peak, cache, journal_dir)
 
 
-def measure(args, cell, devices, peak, cache: str) -> int:
+def build_system(cell, tables: dict, journal_dir: Path | None = None):
+    """The system under test that the cell's mix enters, with the
+    configuration's journal in ``journal_dir``."""
+    if cell.traffic["entry"] == "index":
+        return drive.IndexSystem(cell.config, tables)
+    return drive.EngineSystem(cell.config, tables,
+                              text=cell.traffic["entry"] == "text",
+                              journal_dir=journal_dir,
+                              fsync_every=cell.fsync_every)
+
+
+def measure(args, cell, devices, peak, cache: str,
+            journal_dir: Path | None = None) -> int:
     """Set-up, window, check and report of one run."""
     counter = CompileCounter()
     roots, tables = drive.lexicon(cell.config, args.seed)
     bodies = drive.payloads(cell.traffic, args.seed, args.seconds, roots)
     entry = cell.traffic["entry"]
-    if entry == "index":
-        system = drive.IndexSystem(cell.config, tables)
-    else:
-        system = drive.EngineSystem(cell.config, tables, text=entry == "text")
+    system = build_system(cell, tables, journal_dir)
     system.warm_up(bodies)
     setup_s = time.perf_counter() - T_START
     log(f"set-up {setup_s:.3f} s (compile cache {cache})")
@@ -255,6 +327,10 @@ def measure(args, cell, devices, peak, cache: str) -> int:
         else:
             numbers = check.word_numbers(system, served, bodies, roots,
                                          infix=infix, control=control)
+        if system.journal is not None:
+            numbers += check.journal_numbers(
+                system, served, bodies, fsync_every=cell.fsync_every,
+                control=control)
         attempted = len(served.done) + len(served.missing)
         failed = check.failed_requests(system, served)
 

@@ -13,6 +13,11 @@ and metrics. A cell ``<config>.<traffic>`` resolves to
 
 so a later cell, mix or metric is added as files and entries, without
 an edit to any file already here.
+
+A configuration may state ``"journal": {"fsync_every": N}`` (a whole
+N >= 1, no default): the engine then serves through the program's
+write-ahead journal, fsynced every N records. Without the key, or with
+``null``, it has none. The index builder takes no journal.
 """
 from __future__ import annotations
 
@@ -37,6 +42,7 @@ class Cell:
     end_to_end: list
     per_layer: list
     root: Path
+    fsync_every: int | None = None      # the configuration's journal
 
     def _function(self, folder: str, name: str, fn: str):
         path = self.root / BENCH / folder / f"{name}.py"
@@ -75,6 +81,20 @@ def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def journal_fsync_every(config: dict) -> int | None:
+    """The ``fsync_every`` a configuration's ``journal`` states, or None
+    where it states no journal."""
+    j = config.get("journal")
+    if j is None:
+        return None
+    n = j.get("fsync_every") if isinstance(j, dict) else None
+    if (not isinstance(j, dict) or set(j) != {"fsync_every"}
+            or not isinstance(n, int) or isinstance(n, bool) or n < 1):
+        raise SpecError(f"journal must be null or {{\"fsync_every\": N}}"
+                        f" with a whole N >= 1, not {j!r}")
+    return n
+
+
 def resolve(root: Path, workload: str) -> Cell:
     """The cell named ``workload`` of the checkout at ``root``."""
     root = Path(root)
@@ -84,6 +104,9 @@ def resolve(root: Path, workload: str) -> Cell:
     config = _load(root / entry["file"], "configuration")
     traffic = _load(root / BENCH / "traffic" / f"{cell['traffic']}.json",
                     "traffic")
+    fsync_every = journal_fsync_every(config)
+    if fsync_every is not None and traffic["entry"] == "index":
+        raise SpecError(f"{workload}: the index builder takes no journal")
     layers = []
     for m in bench["per_layer"]:
         if _applies(m, workload):
@@ -94,4 +117,4 @@ def resolve(root: Path, workload: str) -> Cell:
                 traffic=traffic,
                 end_to_end=[m for m in bench["end_to_end"]
                             if _applies(m, workload)],
-                per_layer=layers, root=root)
+                per_layer=layers, root=root, fsync_every=fsync_every)

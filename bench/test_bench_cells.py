@@ -26,11 +26,29 @@ TINY = {
                           "words_max": 300}},
     "index": {"payload": {"chunks": 2, "chunk_words": 4096}},
 }
+# a journaled copy of newswire, only in the tiny checkout, under docs
+JOURNALED = "newswire-journal.docs"
+# each accepted cell's numbers of correct, by name, in order
+CHECKS = {
+    "newswire.docs": ["word_rows", "byte_spans", "doc_ids", "roots",
+                      "requests_failed"],
+    "quran.words": ["roots", "requests_failed"],
+    "newswire.queries": ["word_rows", "byte_spans", "doc_ids", "roots",
+                         "requests_failed"],
+    "newswire-x4.index": ["index_counts", "index_postings"],
+}
+JOURNAL_CHECKS = ["journal_torn", "journal_admits", "journal_retires",
+                  "journal_unflushed", "journal_fsyncs_short"]
+# the journal number each way of breaking durability has to move
+JOURNAL_FAULTS = {"control": "journal_admits", "journal": "journal_admits",
+                  "journal_late": "journal_unflushed",
+                  "journal_fsync": "journal_fsyncs_short"}
 
 
 def _tiny_root(tmp: Path) -> Path:
     """A checkout holding a copy of ``bench/`` with every mix cut to a
-    test's size and every cell on one device."""
+    test's size and every cell on one device, and a journaled copy of
+    ``newswire`` with a cell of its own under the docs mix."""
     shutil.copytree(ROOT / "bench", tmp / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     for name, cut in TINY.items():
@@ -48,6 +66,18 @@ def _tiny_root(tmp: Path) -> Path:
         cfg = json.loads(path.read_text())
         cfg["chips"] = 1
         path.write_text(json.dumps(cfg))
+    config, traffic = JOURNALED.split(".")
+    cfg = json.loads((tmp / "bench" / "configs" / "newswire.json").read_text())
+    cfg.update(name=config, journal={"fsync_every": 2})
+    cfg["guarantees"]["durability"] = "write-ahead journal, fsync every 2"
+    (tmp / "bench" / "configs" / f"{config}.json").write_text(json.dumps(cfg))
+    bench["configs"].append({"name": config, "source": "a test's",
+                             "file": f"bench/configs/{config}.json",
+                             "reduced": [], "why": "journaled newswire"})
+    bench["workloads"].append({"name": JOURNALED, "config": config,
+                               "traffic": traffic, "chips": 1,
+                               "why": "docs through the journal"})
+    bench["end_to_end"][0]["workloads"].append(JOURNALED)
     (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
     return tmp
 
@@ -69,12 +99,14 @@ def harness(monkeypatch, tmp_path):
     return run
 
 
-def _run(harness, root, capsys, cell, fault="none", trace=0):
+def _run(harness, root, capsys, cell, fault="none", trace=0, stderr=False,
+         seconds=0.5):
     """One run of ``cell``; ``fault`` is ``none``, ``control`` (the run's
-    own option) or a fault of ``bench/faults.py`` planted under it."""
+    own option) or a fault of ``bench/faults.py`` planted under it. The
+    result line, and with ``stderr`` what the run logged."""
     from bench import faults
 
-    argv = ["--workload", cell, "--seed", str(SEED), "--seconds", "0.5",
+    argv = ["--workload", cell, "--seed", str(SEED), "--seconds", str(seconds),
             "--trace", str(trace)]
     if fault in ("none", "control"):
         rc = harness.main(argv + ["--fault", fault], root=root)
@@ -86,7 +118,8 @@ def _run(harness, root, capsys, cell, fault="none", trace=0):
             remove()
     out = capsys.readouterr()
     assert rc == 0, out.err[-2000:]
-    return json.loads(out.out.strip().splitlines()[-1])
+    res = json.loads(out.out.strip().splitlines()[-1])
+    return (res, out.err) if stderr else res
 
 
 @pytest.mark.parametrize("cell, fault, correct", [
@@ -114,10 +147,96 @@ def test_correct_catches_control_and_faults(harness, tiny, capsys, cell,
     assert list(res)[:5] == ["correct", "attempted", "failed", "metrics",
                              "device"]
     assert list(res)[-1] == "check"
+    assert list(res["check"]) == CHECKS[cell]
     assert res["attempted"] > 0
     if correct:
         assert all(v["value"] <= v["limit"] for v in res["check"].values())
         assert "setup_s" in res["metrics"]
+
+
+@pytest.mark.parametrize("fault, correct", [
+    ("none", True), ("control", False), ("journal", False),
+    ("journal_late", False), ("journal_fsync", False)])
+def test_a_journaled_cell_reads_every_acknowledged_request_back(
+        harness, tiny, capsys, fault, correct):
+    """The engine serves through the program's journal, in a directory
+    of the checkout that is gone after the run. Clean, every finished
+    request is read back, its admit written before ``submit`` returned
+    and the window's records fsynced in the stated batch; the control's
+    missing admit record, a journal that drops every 50th admit (a
+    window long enough to admit over a hundred requests), one that
+    writes each admit only at its retire and one that fsyncs every
+    1000th record are caught, each by its own number."""
+    res, err = _run(harness, tiny, capsys, JOURNALED, fault, stderr=True,
+                    seconds=2 if fault == "journal" else 0.5)
+    assert res["correct"] is correct, res["check"]
+    assert list(res["check"]) == CHECKS["newswire.docs"] + JOURNAL_CHECKS
+    journal = {k: res["check"][k]["value"] for k in JOURNAL_CHECKS}
+    assert journal["journal_torn"] == 0
+    assert not (tiny / harness.JOURNAL_DIR).exists()
+    assert "journal in" in err and "filesystem" in err
+    counters = dict(line.split()[1::2] for line in err.splitlines()
+                    if line.startswith("counter journal_"))
+    assert int(counters["journal_bytes"]) > 0
+    if fault == "none":
+        assert journal == dict.fromkeys(JOURNAL_CHECKS, 0)
+        # a closed loop admits and retires every request in the window
+        assert int(counters["journal_records"]) == 2 * res["attempted"]
+    else:
+        assert journal[JOURNAL_FAULTS[fault]] >= 1
+    if fault == "control":
+        assert res["check"]["word_rows"]["value"] > 0
+    if fault == "journal_late":
+        assert journal["journal_admits"] == 0
+
+
+def test_a_journal_on_a_memory_filesystem_is_refused(harness, tiny, capsys,
+                                                     monkeypatch):
+    """On tmpfs an fsync makes nothing durable: the run fails, printing
+    no result, and leaves no journal directory behind."""
+    monkeypatch.setattr(harness, "filesystem_type", lambda _path: "tmpfs")
+    rc = harness.main(["--workload", JOURNALED, "--seed", str(SEED),
+                       "--seconds", "0.5", "--trace", "0"], root=tiny)
+    out = capsys.readouterr()
+    assert rc == 1 and out.out == ""
+    assert "filesystem tmpfs" in out.err
+    assert not (tiny / harness.JOURNAL_DIR).exists()
+
+
+@pytest.mark.parametrize("journal", ["absent", None])
+def test_a_configuration_without_a_journal_serves_without_one(tiny, journal):
+    from bench import drive, run, spec
+
+    cell = spec.resolve(tiny, "newswire.docs")
+    assert "journal" not in cell.config and cell.fsync_every is None
+    if journal is None:
+        assert spec.journal_fsync_every({**cell.config, "journal": None}) is None
+    _roots, tables = drive.lexicon(cell.config, SEED)
+    system = run.build_system(cell, tables)
+    assert system.journal is None and system.engine.journal is None
+
+
+@pytest.mark.parametrize("journal", [
+    {}, {"fsync_every": 0}, {"fsync_every": "32"}, {"fsync_every": 1.5},
+    {"fsync_every": True}, {"fsync_every": 32, "path": "/x"}, 32])
+def test_a_journal_states_its_fsync_batch(journal):
+    from bench import spec
+
+    with pytest.raises(spec.SpecError):
+        spec.journal_fsync_every({"journal": journal})
+    assert spec.journal_fsync_every({"journal": {"fsync_every": 7}}) == 7
+
+
+def test_the_index_builder_takes_no_journal(tmp_path):
+    from bench import spec
+
+    root = _tiny_root(tmp_path / "checkout")
+    path = root / "bench" / "configs" / "newswire-x4.json"
+    path.write_text(json.dumps({**json.loads(path.read_text()),
+                                "journal": {"fsync_every": 32}}))
+    with pytest.raises(spec.SpecError, match="no journal"):
+        spec.resolve(root, "newswire-x4.index")
+    assert spec.resolve(root, JOURNALED).fsync_every == 2
 
 
 def _committed() -> dict:

@@ -51,6 +51,15 @@ def test_busy_idle_selected_and_breakdown():
     assert ["submit", 30e-9] in gaps                 # 70-100
 
 
+def test_gaps_are_named_by_the_benchmarks_spans_alone():
+    """The program's spans, now loaded, name no gap: a gap inside
+    ``repro.engine.step`` inside ``Engine.step`` is still the step's."""
+    ev = _events() + [Event(H, "python", "repro.engine.step", 31, 28),
+                      Event(H, "python", "repro.engine.submit", 5, 2)]
+    assert trace.idle_gaps(ev) == trace.idle_gaps(_events())
+    assert trace.idle_gaps(ev)[0] == ["Engine.step", 30e-9]
+
+
 def test_a_trimmed_chip_trace():
     """25 ms of a newswire.docs window recorded on one TPU v5e: the
     reduction's numbers on it, worked out by hand from its events."""
@@ -90,10 +99,13 @@ def test_no_device_op_reads_nothing(tmp_path):
 
 
 def test_xplane_from_a_cpu_profile(tmp_path):
-    """The flattening reads the benchmark's host spans from a real
-    profiler file; a CPU profile has no TPU plane, so no device op."""
+    """The flattening reads the benchmark's host spans and the program's
+    from a real profiler file, and no other host event; a CPU profile has
+    no TPU plane, so no device op."""
     import jax
     import jax.numpy as jnp
+
+    from repro.serve.spans import ENGINE_SUBMIT
 
     f = jax.jit(lambda x: (x * 2).sum())
     x = jnp.ones(8)
@@ -102,11 +114,18 @@ def test_xplane_from_a_cpu_profile(tmp_path):
     with jax.profiler.TraceAnnotation("window"):
         with jax.profiler.TraceAnnotation("Engine.step"):
             f(x).block_until_ready()
+        with jax.profiler.TraceAnnotation("submit"):
+            with jax.profiler.TraceAnnotation(ENGINE_SUBMIT):
+                with jax.profiler.TraceAnnotation("unlisted.span"):
+                    f(x).block_until_ready()
     jax.profiler.stop_trace()
     ev = trace.load_xplane(str(next(tmp_path.rglob("*.xplane.pb"))))
     lo, hi = trace.window(ev)
     assert hi > lo
     assert len(trace.host_spans(ev, "Engine.step")) == 1
+    assert ENGINE_SUBMIT == "repro.engine.submit"
+    assert len(trace.host_spans(ev, ENGINE_SUBMIT)) == 1
+    assert not trace.host_spans(ev, "unlisted.span")
     assert trace.devices(ev) == [] and trace.idle_share(ev) is None
 
 

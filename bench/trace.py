@@ -27,6 +27,8 @@ HOST_PLANE = "/host:CPU"
 WINDOW_SPAN = "window"
 # benchmark spans that name what the host was doing during a device gap
 HOST_SPANS = ("submit", "Engine.step", "wait", "build_corpus_index")
+# the program's own host spans (src/repro/serve/spans.py), kept for readers
+PROGRAM_SPAN_PREFIX = "repro."
 
 
 @dataclass(frozen=True)
@@ -54,9 +56,10 @@ def short_name(name: str) -> str:
 
 
 def load_xplane(path: str) -> list[Event]:
-    """Every event of the device planes' op and module lines, and the
-    benchmark's host spans. An op's ``module`` is the XLA program whose
-    event on the module line encloses it."""
+    """Every event of the device planes' op and module lines, the
+    benchmark's host spans and the program's (``repro.*``). An op's
+    ``module`` is the XLA program whose event on the module line
+    encloses it."""
     from jax.profiler import ProfileData
 
     out = []
@@ -69,7 +72,8 @@ def load_xplane(path: str) -> list[Event]:
             if dev and line.name not in (OPS_LINE, MODULES_LINE):
                 continue
             for ev in line.events:
-                if not dev and ev.name not in HOST_SPANS + (WINDOW_SPAN,):
+                if not dev and not (ev.name in HOST_SPANS + (WINDOW_SPAN,)
+                                    or ev.name.startswith(PROGRAM_SPAN_PREFIX)):
                     continue
                 e = (line.name, ev.name, float(ev.start_ns),
                      float(ev.duration_ns))
