@@ -20,9 +20,11 @@ from jax.profiler import TraceAnnotation as span
 # Engine (serve/engine.py)
 ENGINE_SUBMIT = "repro.engine.submit"   # Engine.submit
 ENGINE_STEP = "repro.engine.step"       # Engine.step
-# text front end (serve/text.py), inside repro.engine.submit
-TEXT_FRONTEND = "repro.text.frontend"   # text -> word rows at admission
-TEXT_FETCH = "repro.text.fetch"         # its blocking device->host reads
+# text front end (serve/text.py), inside repro.engine.step, before the
+# stemmer's spans: one a front-end launch over a tile of queued documents,
+# then one fetch of every tile the tick launched
+TEXT_FRONTEND = "repro.text.frontend"   # text -> word rows, one tile
+TEXT_FETCH = "repro.text.fetch"         # the tick's blocking device->host read
 # StemmerWorkload's launch ring, inside repro.engine.step
 STEM_COALESCE = "repro.stem.coalesce"   # pick a due retry or coalesce FIFO
 STEM_STAGE = "repro.stem.stage"         # copy into the staging buffer

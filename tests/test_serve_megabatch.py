@@ -236,32 +236,6 @@ def test_persistent_serve_flags_checked(dicts, enc):
 # ---------------------------------------------------------------------------
 # the default: launches follow the queue depth, both shapes warmed
 # ---------------------------------------------------------------------------
-class CompileCounter:
-    """XLA compiles and lowerings while ``on``, by jax.monitoring."""
-
-    COMPILE = "/jax/core/compile/backend_compile_duration"
-    LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
-
-    def __init__(self):
-        self.on = False
-        self.compiles = self.lowerings = 0
-
-    def __call__(self, event, _secs, **_kw):
-        if self.on:
-            self.compiles += event == self.COMPILE
-            self.lowerings += event == self.LOWER
-
-
-@pytest.fixture
-def compile_counter():
-    import jax
-
-    counter = CompileCounter()
-    jax.monitoring.register_event_duration_secs_listener(counter)
-    yield counter
-    jax.monitoring.unregister_event_duration_listener(counter)
-
-
 def _launch_rows(workload):
     """Record the staging rows of every launch ``workload`` makes, warm
     launches included: -> the list it appends to."""

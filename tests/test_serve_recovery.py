@@ -272,6 +272,76 @@ def test_text_requests_replay_from_raw_documents(dict_and_words, tmp_path):
     assert [eng2.result(r).analyses() for r in rids] == want
 
 
+def test_text_replay_segments_the_replayed_requests_together(
+        dict_and_words, tmp_path):
+    """Requests accepted but not served before a crash replay through the
+    kernel front end in one launch, bit-identical to an uninterrupted
+    host-front-end run; those retired before the crash are not re-served."""
+    from repro.launch.serve import build_documents
+
+    arrays, _ = dict_and_words
+    docs = build_documents(5, 24, seed=4)
+    payloads = [[docs[0]], docs[1], docs[2:4], [docs[4], ""]]
+    ref = Engine(TextAnalysisWorkload(DictStore(arrays), block_b=32,
+                                      frontend="host"))
+    ref_rids = [ref.submit(p) for p in payloads]
+    assert ref.run_until_drained().drained
+    want = [ref.result(r).analyses() for r in ref_rids]
+
+    jp = tmp_path / "wal.jsonl"
+    eng = Engine(TextAnalysisWorkload(DictStore(arrays), block_b=32,
+                                      char_block=256),
+                 journal=Journal(jp, fsync_every=1))
+    rids = [eng.submit(payloads[0])]
+    assert eng.run_until_drained().drained
+    rids += [eng.submit(p) for p in payloads[1:]]
+    # crash with the last three accepted, none segmented
+    w2 = TextAnalysisWorkload(DictStore(arrays), block_b=32, char_block=256)
+    eng2 = Engine.recover(jp, w2)
+    assert eng2.recovery.replayed == rids[1:]
+    assert eng2.run_until_drained().drained
+    assert w2.frontend_launches == 1
+    assert eng.result(rids[0]).analyses() == want[0]
+    assert [eng2.result(r).analyses() for r in rids[1:]] == want[1:]
+
+
+def test_a_poison_text_request_replays_into_quarantine(
+        dict_and_words, tmp_path, poison_frontend):
+    """A request accepted before a crash whose document the front end
+    cannot process is quarantined after recovery too, its neighbours
+    served bit-identically; its failure is journaled, so a second
+    recovery replays nothing."""
+    arrays, _ = dict_and_words
+    docs = ["كتب الولد درسا", "ذهب الرجل الى السوق", "قرأ الطالب"]
+    ref = Engine(TextAnalysisWorkload(DictStore(arrays), block_b=32,
+                                      frontend="host"))
+    ref_rids = [ref.submit([d]) for d in docs]
+    assert ref.run_until_drained().drained
+    want = [ref.result(r).analyses() for r in ref_rids]
+
+    def workload():
+        w = TextAnalysisWorkload(DictStore(arrays), block_b=32,
+                                 char_block=256)
+        return w, poison_frontend(w, ValueError)
+
+    jp = tmp_path / "wal.jsonl"
+    w, marker = workload()
+    eng = Engine(w, journal=Journal(jp, fsync_every=1))
+    rids = [eng.submit([docs[0]]), eng.submit([docs[1] + " " + marker]),
+            eng.submit([docs[2]])]
+    # crash with all three accepted, none segmented
+    w2, _ = workload()
+    eng2 = Engine.recover(jp, w2)
+    assert eng2.recovery.replayed == rids
+    assert eng2.run_until_drained().drained
+    assert eng2.result(rids[1]).failure.code == "quarantined"
+    assert [eng2.result(rids[i]).analyses() for i in (0, 2)] == [
+        want[0], want[2]]
+    eng2.journal.close()
+    w3, _ = workload()
+    assert Engine.recover(jp, w3).recovery.replayed == []
+
+
 # ---------------------------------------------------------------------------
 # the stall watchdog
 # ---------------------------------------------------------------------------

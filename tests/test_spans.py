@@ -122,18 +122,28 @@ def test_text_frontend_spans(tmp_path, lexicon, documents):
                                     frontend="kernel")
 
     plain = _serve(workload(), documents)
-    traced, found = _profiled(tmp_path,
-                              lambda: _serve(workload(), documents))
+    work = workload()
+    traced, found = _profiled(tmp_path, lambda: _serve(work, documents))
     _same_results(plain, traced)
     for a, b in zip(plain, traced):
         np.testing.assert_array_equal(a.spans, b.spans)
         np.testing.assert_array_equal(a.doc_ids, b.doc_ids)
     assert {s[0] for s in found} == set(spans.ALL)
-    for name in (spans.ENGINE_SUBMIT, spans.TEXT_FRONTEND, spans.TEXT_FETCH):
-        assert len(_named(found, name)) == len(documents)
-    _assert_nested(found, spans.TEXT_FRONTEND, spans.ENGINE_SUBMIT)
-    _assert_nested(found, spans.TEXT_FETCH, spans.TEXT_FRONTEND)
+    assert len(_named(found, spans.ENGINE_SUBMIT)) == len(documents)
+    # the front end runs once a launch over every queued document, inside
+    # Engine.step: the three requests submitted before the first step
+    # share one launch, and the tick fetches it after it is launched
+    assert work.frontend_launches == 1 < len(documents)
+    for name in (spans.TEXT_FRONTEND, spans.TEXT_FETCH):
+        assert len(_named(found, name)) == work.frontend_launches
+        _assert_nested(found, name, spans.ENGINE_STEP)
+    (launch,), (fetch,) = (_named(found, spans.TEXT_FRONTEND),
+                           _named(found, spans.TEXT_FETCH))
+    assert launch[3] <= fetch[2]
     _assert_stem_tree(found)
+    text = _named(found, spans.TEXT_FRONTEND, spans.TEXT_FETCH)
+    for leaf in _named(found, *STEM_LEAVES):
+        assert all(t[3] <= leaf[2] or leaf[3] <= t[2] for t in text)
 
 
 def test_no_verify_span_without_checksum(tmp_path, lexicon, word_batches):
