@@ -61,8 +61,11 @@ def build_inputs():
 
 
 def drain(arrays, enc, *, injector=None, **kw):
+    # one tile a launch: the 8-tile queue then takes several launches,
+    # so a fault on one of them lands mid-ring
     eng = Engine(StemmerWorkload(DictStore(arrays), block_b=32,
-                                 max_inflight=2, injector=injector, **kw))
+                                 max_inflight=2, megabatch_tiles=1,
+                                 injector=injector, **kw))
     rids = [eng.submit(enc[i * WORDS_PER_REQ:(i + 1) * WORDS_PER_REQ])
             for i in range(N_REQ)]
     assert eng.run_until_drained().drained

@@ -24,13 +24,17 @@ from repro.serve import (DictStore, Engine, FaultInjector, FaultPlan,
                          FaultSpec, StemmerWorkload)
 
 N_REQUESTS, WORDS_PER_REQ, BLOCK_B = 12, 40, 64
+# one tile a launch (the workload's default coalesces this whole queue
+# into one launch): the swap and the injected fault then land mid-stream
+MEGABATCH_TILES = 1
 
 
 def main():
     d = corpus.build_dictionary(n_tri=800, n_quad=100, seed=0)
     arrays = stemmer.RootDictArrays.from_rootdict(d)
     store = DictStore(arrays)
-    eng = Engine(StemmerWorkload(store, block_b=BLOCK_B))
+    eng = Engine(StemmerWorkload(store, block_b=BLOCK_B,
+                                 megabatch_tiles=MEGABATCH_TILES))
 
     words, _, _ = corpus.build_corpus(n_words=N_REQUESTS * WORDS_PER_REQ,
                                       seed=1)
@@ -52,6 +56,7 @@ def main():
           f"({n_words / dt:.1f} Wps, {rep.ticks} ticks)")
     print(f"dict versions served: {split} (hot swap published v{v1} "
           f"mid-stream)")
+    assert sorted(split) == [0, v1], "the hot swap did not land mid-stream"
 
     # bit-exact parity per served version against the batch stemmer
     checked = 0
@@ -75,6 +80,7 @@ def main():
     # -- faulted re-serve, observed through the structured event stream
     inj = FaultInjector(FaultPlan(specs=(FaultSpec("dispatch", at=1),)))
     eng2 = Engine(StemmerWorkload(DictStore(arrays), block_b=BLOCK_B,
+                                  megabatch_tiles=MEGABATCH_TILES,
                                   injector=inj))
     rids2 = [eng2.submit(enc[i * WORDS_PER_REQ:(i + 1) * WORDS_PER_REQ])
              for i in range(N_REQUESTS)]

@@ -1,8 +1,8 @@
 """llama-3.2-vision-11b [vlm] — 32 self + 8 interleaved cross-attention
 layers (40 total), GQA kv=8 [hf:meta-llama/Llama-3.2-11B-Vision].
 
-The vision frontend is a STUB per the assignment: input_specs() provides
-precomputed patch embeddings [B, 1601, d_model]; only the transformer
+The vision frontend is a stub: the model takes precomputed patch
+embeddings [B, vision_seq=1601, d_model] as input; only the transformer
 backbone is modelled.
 """
 from repro.configs.base import ModelConfig

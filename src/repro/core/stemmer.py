@@ -233,15 +233,14 @@ def _match(keys, dict_keys, backend: str):
         return match_dense(keys, dict_keys)
     if backend == "sorted":
         return match_sorted(keys, dict_keys)
-    if backend in ("pallas", "fused"):
+    if backend == "fused":
         from repro.kernels import ops  # lazy: kernels depend on core
 
         # "fused" reaching stage 5 in isolation (e.g. through the extended
         # rule pool) uses the megakernel's in-kernel sorted search.
-        strategy = "bsearch" if backend == "fused" else "bank"
         shape = keys.shape
         return ops.dict_match(
-            keys.reshape(-1), dict_keys, strategy=strategy).reshape(shape)
+            keys.reshape(-1), dict_keys, strategy="bsearch").reshape(shape)
     raise ValueError(f"unknown match backend: {backend}")
 
 
@@ -270,9 +269,9 @@ def extract_roots(
     pinned residency then overrides the residency argument.
 
     backend selects the Compare stage implementation: "dense" / "sorted"
-    (pure jnp), "pallas" (tiled comparator-bank kernel) or "fused" — the
-    single-launch stage 1-5 megakernel (kernels/stem_fused.py;
-    paper-exact, no intermediate HBM tensors). For the fused backend,
+    (pure jnp references) or "fused" — the single-launch stage 1-5
+    megakernel (kernels/stem_fused.py; paper-exact, no intermediate HBM
+    tensors). For the fused backend,
     residency picks the dictionary layout: "resident" (VMEM-held),
     "streamed" (a scalar-prefetched tile-visit sweep fed by an explicit
     DMA ladder — unbounded dictionary size), or "auto" (default:

@@ -4,9 +4,7 @@ analyzer as a first-class preprocessing operator.
 `morph_lm_batches` is the integration point (DESIGN.md §4): a stream of
 Arabic verb forms is encoded to character tokens while the batched JAX
 stemmer produces per-word root ids — usable as auxiliary labels
-(root-prediction heads) or for root-aware vocabulary reduction. The
-stemmer runs at MWps throughput (see benchmarks/throughput.py), so it
-never bottlenecks the input pipeline.
+(root-prediction heads) or for root-aware vocabulary reduction.
 """
 from __future__ import annotations
 
@@ -41,7 +39,7 @@ class MorphPreprocessor:
     """Batched root extraction as a pipeline operator.
 
     backend is any core.stemmer Compare backend ("sorted" / "dense" /
-    "pallas" / "fused" — the last runs the single-launch stage 1-5
+    "fused" — the last runs the single-launch stage 1-5
     megakernel, see kernels/stem_fused.py). For the fused backend,
     residency picks the megakernel's dictionary layout ("resident" /
     "streamed" / "auto"; "auto" streams production-size dictionaries

@@ -16,7 +16,6 @@ import numpy as np
 
 from repro.core import pyref
 from repro.core import stemmer as core_stemmer
-from repro.kernels import ref as kref
 from repro.kernels import stem_datapath as sdp
 from repro.kernels import stem_fused as sf
 from repro.kernels import stem_match as sm
@@ -31,8 +30,8 @@ def _interpret_default() -> bool:
 # since the last reset. A Python counter inside a jitted function would
 # only tick at trace time, so each wrapper adds what its configuration is
 # *known* to launch (stem_fused.planned_launches mirrors the kernel's
-# chunking exactly). The launch_overhead benchmark and the megabatch
-# launch-count tests read this.
+# chunking exactly). The benchmark's launch counts and the launch-count
+# tests read this.
 _dispatches = 0
 
 
@@ -572,39 +571,6 @@ def _finish_index_text(root, source, spans, n_words, vocab, byte_off,
                                     block_w=block_w, interpret=interpret)
     return pk.finish_postings(hist, rank, ids, doc_local + doc0, positions,
                               n_roots=vocab.shape[0], block_w=block_w)
-
-
-@functools.partial(jax.jit, static_argnames=("infix", "interpret"))
-def extract_roots_multilaunch(words, roots, *, infix: bool = True,
-                              interpret: bool | None = None):
-    """The pre-megakernel pipeline: datapath kernel -> 5 match kernel
-    launches -> priority select, with keys/valid/hit masks round-tripping
-    through HBM between launches. Kept as the baseline the megakernel is
-    benchmarked against (benchmarks/throughput.py).
-    """
-    if interpret is None:
-        interpret = _interpret_default()
-    keys, valid = sdp.stem_datapath_pallas(words, interpret=interpret)
-    b = words.shape[0]
-
-    n_groups = 5 if infix else 2
-    dicts = [roots.tri, roots.quad, roots.tri, roots.tri, roots.bi][:n_groups]
-    hits = []
-    for g, dk in enumerate(dicts):
-        sl = keys[:, g * 6 : (g + 1) * 6].reshape(-1)
-        hit = sm.dict_match_pallas(sl, dk, interpret=interpret).reshape(b, 6)
-        hits.append(hit & (valid[:, g * 6 : (g + 1) * 6] > 0))
-    all_hits = jnp.concatenate(hits, axis=1)
-
-    first = jnp.argmax(all_hits, axis=1)
-    found = all_hits.any(axis=1)
-    chosen_keys = jnp.take_along_axis(keys[:, : n_groups * 6], first[:, None], 1)[:, 0]
-    root = jnp.where(found[:, None], unpack_keys(chosen_keys), 0)
-    tags = jnp.asarray(
-        [t for t in kref.GROUP_TAGS[:n_groups] for _ in range(6)], jnp.int32
-    )
-    source = jnp.where(found, tags[first], pyref.SRC_NONE)
-    return root, source
 
 
 def autotune_stem_fused(words, roots, *, infix: bool = True,

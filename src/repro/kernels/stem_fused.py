@@ -729,7 +729,7 @@ def planned_launches(n_words: int, roots, *, infix: bool = True,
                      visit_budget: int | None = None) -> int:
     """``pallas_call`` dispatches one :func:`stem_fused_pallas` invocation
     issues for this configuration — the launch accounting behind
-    ``ops.dispatch_count()`` and the ``launch_overhead`` benchmark.
+    ``ops.dispatch_count()``.
 
     Resident launches are always 1; streamed (and persistent-streamed)
     launches are ceil(batch_tiles / chunk) where chunk is the largest

@@ -1,8 +1,7 @@
 """Serving launcher: workload-agnostic continuous-batching engine.
 
-  PYTHONPATH=src python -m repro.launch.serve --workload lm --arch llama3-8b --requests 8
-  PYTHONPATH=src python -m repro.launch.serve --workload stemmer --requests 16
   PYTHONPATH=src python -m repro.launch.serve --workload text --requests 16
+  PYTHONPATH=src python -m repro.launch.serve --workload stemmer --requests 16
 """
 from __future__ import annotations
 
@@ -10,27 +9,20 @@ import argparse
 import sys
 import time
 
-import jax
-import numpy as np
-
-from repro import configs
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models import model as model_mod
-from repro.models import params as pm
 from repro.serve import (DegradationPolicy, DictStore, Engine, Journal,
-                         LMDecodeWorkload, StemmerWorkload,
-                         TextAnalysisWorkload)
+                         StemmerWorkload, TextAnalysisWorkload)
 from repro.serve.engine import MEGABATCH_TILES
 
 
 def _engine_kw(args) -> dict:
-    """Engine admission-control + crash-safety kwargs shared by all
-    three workloads (the journal/policy flags are validated in main()
-    before any engine is constructed)."""
+    """Engine admission-control + crash-safety kwargs shared by both
+    workloads (the journal/policy flags are validated in main() before
+    any engine is constructed)."""
     kw = dict(queue_cap=args.queue_cap or None, on_full=args.on_full)
-    if getattr(args, "journal", None):
+    if args.journal:
         kw["journal"] = Journal(args.journal)
-    if getattr(args, "degrade", "off") == "on":
+    if args.degrade == "on":
         kw["policy"] = DegradationPolicy()
     return kw
 
@@ -40,7 +32,7 @@ def _deadline_s(args) -> float | None:
 
 
 def _retry_kw(args) -> dict:
-    """StemmerWorkload/TextAnalysisWorkload retry kwargs (lm has none)."""
+    """StemmerWorkload/TextAnalysisWorkload retry kwargs."""
     kw = {} if args.max_retries is None else dict(
         max_retries=args.max_retries)
     if args.watchdog_ms:
@@ -77,46 +69,7 @@ def _report_failures(failed, eng) -> str:
     return f", {len(failed)} failed, {eng.shed} shed" if failed else ""
 
 
-def required_cache_len(prompt_len: int, max_new: int) -> int:
-    """KV positions a request writes: prompt_len prefill steps plus
-    max_new - 1 decode steps (the last emitted token is never fed back)."""
-    return prompt_len + max_new - 1
-
-
-def serve_lm(args) -> None:
-    need = required_cache_len(args.prompt_len, args.max_new)
-    cache_len = args.cache_len if args.cache_len else need
-    if cache_len < need:
-        raise SystemExit(
-            f"--cache-len {cache_len} would overflow: prompt_len"
-            f" {args.prompt_len} + max_new {args.max_new} needs >= {need}"
-            " cache positions")
-
-    cfg = configs.smoke_config(configs.get_config(args.arch))
-    params = pm.init_params(model_mod.model_spec(cfg), jax.random.key(0))
-    eng = Engine(LMDecodeWorkload(cfg, params, max_batch=args.max_batch,
-                                  cache_len=cache_len), **_engine_kw(args))
-
-    rng = np.random.default_rng(0)
-    t0 = time.time()
-    rids = [
-        eng.submit(rng.integers(0, cfg.vocab, args.prompt_len),
-                   max_new=args.max_new, deadline_s=_deadline_s(args))
-        for _ in range(args.requests)
-    ]
-    rep = eng.run_until_drained()
-    dt = time.time() - t0
-    failed = _failed(eng, rids)
-    total_tokens = sum(len(eng.result(r).tokens_out) for r in rids)
-    print(f"served {args.requests} requests / {total_tokens} tokens in "
-          f"{dt:.2f}s ({total_tokens / dt:.1f} tok/s, {rep.ticks} ticks, "
-          f"cache_len {cache_len}{_report_failures(failed, eng)})")
-    for rid in rids[:4]:
-        print(f"  req {rid}: {eng.result(rid).tokens_out}")
-    return len(failed)
-
-
-def serve_stemmer(args) -> None:
+def serve_stemmer(args) -> int:
     from repro.core import corpus, stemmer
 
     d = corpus.build_dictionary(n_tri=1000, n_quad=120, seed=0)
@@ -182,7 +135,7 @@ def build_documents(n_docs: int, words_per_doc: int, seed: int = 1):
     return docs
 
 
-def serve_text(args) -> None:
+def serve_text(args) -> int:
     from repro.core import corpus, stemmer
 
     d = corpus.build_dictionary(n_tri=1000, n_quad=120, seed=0)
@@ -227,18 +180,9 @@ def serve_text(args) -> None:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--workload", choices=("lm", "stemmer", "text"),
-                    default="lm")
+    ap.add_argument("--workload", choices=("text", "stemmer"),
+                    default="text")
     ap.add_argument("--requests", type=int, default=8)
-    # lm knobs
-    ap.add_argument("--arch", default="llama3-8b")
-    ap.add_argument("--max-batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=8)
-    ap.add_argument("--max-new", type=int, default=8)
-    ap.add_argument("--cache-len", type=int, default=0,
-                    help="KV cache positions per slot (default: derived"
-                         " from --prompt-len + --max-new; explicit values"
-                         " too small for that are rejected)")
     # stemmer knobs
     ap.add_argument("--words-per-request", type=int, default=64)
     ap.add_argument("--block-b", type=int, default=256)
@@ -283,8 +227,7 @@ def main():
                          " 'deadline' (0 = no deadline)")
     ap.add_argument("--max-retries", type=int, default=None,
                     help="launch retries before bisect/quarantine"
-                         " (stemmer/text only; 0 = strict fail-fast,"
-                         " default 2)")
+                         " (0 = strict fail-fast, default 2)")
     ap.add_argument("--queue-cap", type=int, default=0,
                     help="admission-control bound on queued requests"
                          " (0 = unbounded)")
@@ -310,8 +253,7 @@ def main():
                          " faults or queue pressure the serving mode"
                          " downshifts persistent -> megabatch ->"
                          " per-tile -> streamed-dict -> fewer devices,"
-                         " and upshifts when healthy (stemmer/text"
-                         " only)")
+                         " and upshifts when healthy")
     args = ap.parse_args()
 
     if args.deadline_ms < 0:
@@ -323,9 +265,6 @@ def main():
     if args.on_full != "raise" and not args.queue_cap:
         ap.error(f"--on-full {args.on_full} needs --queue-cap > 0"
                  " (an unbounded queue is never full)")
-    if args.workload == "lm" and args.max_retries is not None:
-        ap.error("--max-retries applies to the stemmer/text workloads"
-                 " (the LM decode loop has no launch retry path)")
     # cross-validate the crash-safety flags BEFORE any engine exists, so
     # an invalid combination never half-constructs serving state
     if args.watchdog_ms < 0:
@@ -333,15 +272,9 @@ def main():
     if args.watchdog_ms and not args.persistent:
         ap.error("--watchdog-ms guards the persistent descriptor ring;"
                  " it requires --persistent")
-    if args.watchdog_ms and args.workload == "lm":
-        ap.error("--watchdog-ms applies to the stemmer/text workloads")
-    if args.degrade == "on" and args.workload == "lm":
-        ap.error("--degrade applies to the stemmer/text workloads (the"
-                 " LM decode loop has no mode ladder)")
 
     enable_compile_cache()
-    serve = {"stemmer": serve_stemmer, "text": serve_text}.get(
-        args.workload, serve_lm)
+    serve = {"stemmer": serve_stemmer, "text": serve_text}[args.workload]
     n_failed = serve(args)
     if n_failed:
         print(f"{n_failed} request(s) failed", file=sys.stderr)

@@ -257,8 +257,7 @@ class DictStore:
         merged on the host and re-uploaded; untouched tables share the
         version's device arrays, so for large lexicons a small delta
         costs O(delta + touched table) instead of a whole-lexicon
-        re-upload (the swap-latency rows in
-        benchmarks/serve_throughput.py measure the difference).
+        re-upload.
 
         Removing a key that is not present raises ValueError (a delta
         that doesn't apply cleanly is a caller bug, not a no-op), as

@@ -5,13 +5,7 @@ batching — the FIFO request queue, rid allocation, admission while the
 workload has capacity, the finished table, and drain accounting. What a
 "tick" of work means is delegated to a :class:`Workload`:
 
-  LMDecodeWorkload   the LM decode path: a fixed pool of B slots,
-                     prefill-by-decode splicing the prompt's KV into the
-                     slot's region of the batched cache, one decoded
-                     token per live slot per tick, finished slots free
-                     immediately. Bit-identical to the pre-refactor
-                     ServeEngine (which remains as a facade).
-  StemmerWorkload    the paper's workload behind the same machinery:
+  StemmerWorkload    the paper's workload behind that machinery:
                      queued word-batch requests coalesce into megabatches
                      of up to ``megabatch_tiles`` [data_devices *
                      block_b, 16] super-tiles, each megabatch ONE
@@ -31,6 +25,9 @@ workload has capacity, the finished table, and drain accounting. What a
                      served word records the dict version that actually
                      served it, even when the publish lands while its
                      tile is in flight.
+  TextAnalysisWorkload  (serve/text.py) the same ring behind the text
+                     front end: raw UTF-8 documents in, roots with their
+                     source and byte span out.
 
 Keeping the tile shape fixed means every launch replays the same jit
 trace; dictionary swaps with matching shapes also replay it (the
@@ -60,7 +57,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import alphabet as ab
-from repro.models import model as model_mod
 from repro.serve.faults import DeviceLost, FailureInfo
 from repro.serve.health import EventLog
 from repro.serve.spans import (ENGINE_STEP, ENGINE_SUBMIT, STEM_COALESCE,
@@ -100,7 +96,7 @@ class Workload(Protocol):
         """Can admit() take one more request right now?"""
 
     def admit(self, request) -> None:
-        """Move a queued request in-flight (e.g. prefill into a slot)."""
+        """Move a queued request in-flight (e.g. stage it for a launch)."""
 
     def tick(self) -> list:
         """Advance all in-flight work one step; return finished requests."""
@@ -409,149 +405,6 @@ class Engine:
 
 
 # ---------------------------------------------------------------------------
-# LM decode workload (the pre-refactor ServeEngine body)
-# ---------------------------------------------------------------------------
-@dataclass
-class Request:
-    rid: int
-    prompt: np.ndarray          # int32 [T] (or [T,K] audio)
-    max_new: int = 16
-    tokens_out: list = field(default_factory=list)
-    done: bool = False
-    deadline: float | None = None       # absolute time.monotonic() bound
-    failure: FailureInfo | None = None  # set iff terminally failed
-
-
-class LMDecodeWorkload:
-    """Slot-per-request greedy decode over the jitted decode step.
-
-    Requests enter a fixed pool of B slots; prefill computes the
-    prompt's KV (state) which is spliced into the slot's region of the
-    batched cache; every tick decodes one token for all live slots;
-    finished slots free immediately for the next queued request.
-    """
-
-    def __init__(self, cfg, params, *, max_batch: int = 4,
-                 cache_len: int = 128, greedy: bool = True):
-        self.cfg = cfg
-        self.params = params
-        self.B = max_batch
-        self.cache_len = cache_len
-        self.greedy = greedy
-        self.caches = model_mod.init_caches(cfg, max_batch, cache_len)
-        self.slot_req: list[Request | None] = [None] * max_batch
-        self.slot_pos = np.zeros(max_batch, np.int32)   # next position
-
-        self._decode = jax.jit(
-            lambda p, tok, caches, pos: model_mod.decode_step(
-                p, cfg, tok, caches, pos))
-
-    # -- workload protocol -------------------------------------------------
-    def make_request(self, rid: int, prompt, *, max_new: int = 16) -> Request:
-        if max_new < 1:
-            # prefill always emits the first generated token, so the engine
-            # cannot return fewer than one token per request
-            raise ValueError(f"max_new must be >= 1, got {max_new}")
-        return Request(rid, np.asarray(prompt, np.int32), max_new)
-
-    def has_capacity(self) -> bool:
-        return any(r is None for r in self.slot_req)
-
-    def admit(self, req: Request):
-        self._prefill_into_slot(self.slot_req.index(None), req)
-
-    @property
-    def active(self) -> int:
-        return sum(r is not None for r in self.slot_req)
-
-    def pending_rids(self) -> list[int]:
-        return [r.rid for r in self.slot_req if r is not None]
-
-    def tick(self) -> list[Request]:
-        """Decode one token for every live slot.
-
-        Doneness is checked BEFORE decoding: a request admitted this tick
-        already holds its prefill-emitted token, so with max_new=1 it must
-        free its slot without an extra decode (it would otherwise return
-        max_new + 1 tokens).
-        """
-        finished = []
-        for slot in range(self.B):
-            req = self.slot_req[slot]
-            if req is None:
-                continue
-            if len(req.tokens_out) >= req.max_new:
-                finished.append(self._finish_slot(slot, req))
-                continue
-            self._step_slot(slot, req.tokens_out[-1], emit=True)
-            if len(req.tokens_out) >= req.max_new:
-                finished.append(self._finish_slot(slot, req))
-        return finished
-
-    def expire(self, now: float) -> list[Request]:
-        """Free + fail slots whose request deadline passed; partial
-        tokens stay on the request for the caller to inspect."""
-        out = []
-        for slot in range(self.B):
-            req = self.slot_req[slot]
-            if (req is not None and req.deadline is not None
-                    and now > req.deadline):
-                req.failure = FailureInfo(
-                    req.rid, "deadline",
-                    detail=f"{len(req.tokens_out)}/{req.max_new} tokens"
-                           " decoded")
-                out.append(self._finish_slot(slot, req))
-        return out
-
-    def cancel_pending(self) -> list[Request]:
-        out = []
-        for slot in range(self.B):
-            req = self.slot_req[slot]
-            if req is not None:
-                req.failure = FailureInfo(
-                    req.rid, "cancelled",
-                    detail="slot torn down with the request decoding")
-                out.append(self._finish_slot(slot, req))
-        return out
-
-    # -- decode machinery --------------------------------------------------
-    def _prefill_into_slot(self, slot: int, req: Request):
-        """Prompt tokens run through decode steps into this slot's cache.
-
-        (Single-slot prefill-by-decode keeps the engine simple and exactly
-        consistent with the decode path; bulk prefill would jit
-        forward(mode='prefill') and splice — see launch/serve.py.)
-        """
-        self.slot_req[slot] = req
-        self.slot_pos[slot] = 0
-        for t, tok in enumerate(req.prompt[:-1]):
-            self._step_slot(slot, int(tok), emit=False)
-        # last prompt token emits the first generated token
-        self._step_slot(slot, int(req.prompt[-1]), emit=True)
-
-    def _step_slot(self, slot: int, token: int, emit: bool):
-        cfg = self.cfg
-        tok_shape = (self.B, 1, cfg.n_codebooks) if cfg.n_codebooks else (self.B, 1)
-        toks = np.zeros(tok_shape, np.int32)
-        toks[slot] = token
-        pos = jnp.int32(int(self.slot_pos[slot]))
-        logits, new_caches = self._decode(self.params, jnp.asarray(toks),
-                                          self.caches, pos)
-        # merge only this slot's cache rows (positions differ per slot)
-        self.caches = _merge_slot(self.caches, new_caches, slot, batch=self.B)
-        self.slot_pos[slot] += 1
-        if emit:
-            req = self.slot_req[slot]
-            nxt = int(np.asarray(jnp.argmax(logits[slot, -1], axis=-1)).reshape(-1)[0])
-            req.tokens_out.append(nxt)
-
-    def _finish_slot(self, slot: int, req: Request) -> Request:
-        req.done = True
-        self.slot_req[slot] = None
-        return req
-
-
-# ---------------------------------------------------------------------------
 # stemmer workload: word-batch requests through the megakernel
 # ---------------------------------------------------------------------------
 @dataclass
@@ -781,7 +634,7 @@ class StemmerWorkload:
         self._requeue: list[RetryGroup] = []
         self.ticks_launched = 0   # megakernel launches (not engine ticks)
         self._warmed: set = set()  # launch signatures whose buckets compiled
-        # fault-path accounting (tests + benchmarks/recovery.py read these)
+        # fault-path accounting
         self.retries_total = 0    # failed dispatch attempts charged
         self.bisections = 0       # groups split after exhausting retries
         self.quarantined = 0      # requests isolated with FailureInfo
@@ -1419,38 +1272,3 @@ class StemmerWorkload:
                 req.dict_versions[r0:r0 + take] = entry.version
                 req.served += take
         return True
-
-
-# ---------------------------------------------------------------------------
-# back-compat facade
-# ---------------------------------------------------------------------------
-class ServeEngine(Engine):
-    """The original LM-serving entry point: Engine + LMDecodeWorkload.
-
-    Construction signature and decode outputs are unchanged from the
-    pre-refactor ServeEngine; run_until_drained now returns a
-    DrainReport and (per the undrained-work fix) raises EngineUndrained
-    instead of silently dropping queued requests at max_ticks.
-    """
-
-    def __init__(self, cfg, params, *, max_batch: int = 4,
-                 cache_len: int = 128, greedy: bool = True):
-        super().__init__(LMDecodeWorkload(cfg, params, max_batch=max_batch,
-                                          cache_len=cache_len, greedy=greedy))
-
-
-def _merge_slot(old, new, slot: int, batch: int | None = None):
-    """Take slot `slot`'s rows from `new`, keep others from `old`.
-
-    Cache layout: batch dim is index 1 ([L, B, ...]) except grouped VLM
-    self-caches ([G, g, B, ...]) where it is index 2.
-    """
-    if batch is None:
-        batch = max(x.shape[1] for x in jax.tree.leaves(new))
-
-    def merge(o, n):
-        if o.ndim >= 2 and o.shape[1] == batch:
-            return o.at[:, slot].set(n[:, slot])
-        return o.at[:, :, slot].set(n[:, :, slot])
-
-    return jax.tree.map(merge, old, new)

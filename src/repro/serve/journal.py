@@ -118,16 +118,13 @@ def payload_digest(payload) -> str:
 
 
 def response_digest(req) -> str | None:
-    """Content hash of a finished request's response: (roots, sources)
-    for stemmer/text requests, the token list for LM requests — the
-    integrity anchor crash-restart tests compare against."""
+    """Content hash of a finished request's response, its (roots,
+    sources) — the integrity anchor crash-restart tests compare
+    against."""
     roots = getattr(req, "roots", None)
     if roots is not None:
         return _sha16(np.ascontiguousarray(roots).tobytes()
                       + np.ascontiguousarray(req.sources).tobytes())
-    toks = getattr(req, "tokens_out", None)
-    if toks is not None:
-        return _sha16(json.dumps([int(t) for t in toks]).encode())
     return None
 
 

@@ -113,14 +113,3 @@ def test_fused_pipeline_matches_pyref(dicts):
         got = tuple(int(c) for c in np.asarray(roots)[i] if c)
         assert got == want_root, w
         assert int(srcs[i]) == want_src, w
-
-
-def test_pallas_backend_in_core_stemmer(dicts):
-    """'pallas' backend is selectable from the core public API."""
-    _, da = dicts
-    words, _, _ = corpus.build_corpus(n_words=128, seed=13)
-    enc = jnp.asarray(corpus.encode_corpus(words))
-    r1, s1 = stemmer.stem_batch(enc, da, backend="pallas")
-    r2, s2 = stemmer.stem_batch(enc, da, backend="sorted")
-    np.testing.assert_array_equal(np.asarray(r1), np.asarray(r2))
-    np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))

@@ -506,6 +506,31 @@ def test_ladder_transition_serves_bit_identical(dict_and_words, baseline):
         np.testing.assert_array_equal(req.roots, baseline[i])
 
 
+RUNGS = build_ladder(persistent=True, megabatch_tiles=2, data_devices=1,
+                     resident_dict=True)
+
+
+@pytest.mark.parametrize("mode", RUNGS, ids=[m.label for m in RUNGS])
+def test_every_ladder_rung_serves_bit_identical(dict_and_words, baseline,
+                                                mode):
+    """Each rung of the one-device ladder (persistent, megabatch,
+    per-tile, streamed-dict), held from the first tick, serves every
+    request bit-identically to the default workload."""
+    arrays, enc = dict_and_words
+    w = StemmerWorkload(DictStore(arrays), block_b=32, max_inflight=2,
+                        persistent=True, megabatch_tiles=2)
+    w.request_mode(mode)
+    eng = Engine(w)
+    rids = [eng.submit(enc[i * WPR:(i + 1) * WPR]) for i in range(N_REQ)]
+    assert eng.run_until_drained().drained
+    assert (w.persistent, w.megabatch_tiles, w.residency_override) == (
+        mode.persistent, mode.megabatch_tiles, mode.residency)
+    for i, r in enumerate(rids):
+        req = eng.result(r)
+        assert req.failure is None
+        np.testing.assert_array_equal(req.roots, baseline[i])
+
+
 # ---------------------------------------------------------------------------
 # the structured event stream
 # ---------------------------------------------------------------------------
@@ -534,8 +559,8 @@ def test_events_surface_failures_and_recovery(dict_and_words, tmp_path):
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("argv", [
     ["--workload", "stemmer", "--watchdog-ms", "50"],        # no --persistent
-    ["--workload", "lm", "--watchdog-ms", "50"],
-    ["--workload", "lm", "--degrade", "on"],
+    ["--workload", "lm"],                                    # not a workload
+    ["--workload", "text", "--on-full", "shed"],             # no --queue-cap
     ["--workload", "stemmer", "--watchdog-ms", "-1", "--persistent"],
 ])
 def test_serve_launcher_rejects_bad_flag_combos(argv, monkeypatch):

@@ -170,20 +170,6 @@ def test_fused_backend_in_morph_preprocessor():
     assert (ids_f > 0).all()
 
 
-@pytest.mark.parametrize("infix", [True, False])
-def test_multilaunch_baseline_matches_core(dicts, infix):
-    """The pre-megakernel 6-launch path stays correct — it is the baseline
-    behind the fused-vs-multilaunch benchmark ratio."""
-    _, da = dicts
-    words, _, _ = corpus.build_corpus(n_words=300, seed=5)
-    enc = jnp.asarray(corpus.encode_corpus(words))
-    r1, s1 = ops.extract_roots_multilaunch(enc, da, infix=infix,
-                                           interpret=True)
-    r2, s2 = stemmer.stem_batch(enc, da, infix=infix)
-    np.testing.assert_array_equal(np.asarray(r1), np.asarray(r2))
-    np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
-
-
 def test_empty_batch(dicts):
     _, da = dicts
     root, src = ops.extract_roots_fused(

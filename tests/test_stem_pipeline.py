@@ -109,14 +109,24 @@ def test_pipeline_through_public_api_256k(small):
 # ---------------------------------------------------------------------------
 # the visit index
 # ---------------------------------------------------------------------------
-def test_skip_index_visits_fewer_tiles_on_big_dict(big, enc):
-    on = sf.tile_visit_stats(enc, big, block_b=128, dict_block_r=8,
+def _assert_skip_index_prunes(enc, da):
+    on = sf.tile_visit_stats(enc, da, block_b=128, dict_block_r=8,
                              skip_index=True)
-    off = sf.tile_visit_stats(enc, big, block_b=128, dict_block_r=8,
+    off = sf.tile_visit_stats(enc, da, block_b=128, dict_block_r=8,
                               skip_index=False)
     assert off["visited"] == off["full_sweep"]
     assert on["visited"] < off["visited"]          # the acceptance bar
     assert on["full_sweep"] == off["full_sweep"]
+
+
+def test_skip_index_visits_fewer_tiles_on_big_dict(big, enc):
+    _assert_skip_index_prunes(enc, big)
+
+
+def test_skip_index_visits_fewer_tiles_at_128k_keys(small, enc):
+    da = corpus.grow_root_arrays(small, 131_072, seed=2)
+    assert da.n_keys >= 131_072
+    _assert_skip_index_prunes(enc, da)
 
 
 def test_visit_stats_excludes_bi_without_infix(big, enc):

@@ -159,6 +159,15 @@ def test_sorted_equals_dense_backend(dicts):
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
 
 
+@pytest.mark.parametrize("backend", ["pallas", "bank"])
+def test_unknown_backend_raises(dicts, backend):
+    """Only "dense", "sorted" and "fused" name a Compare backend."""
+    _, da = dicts
+    enc = corpus.encode_corpus(["كتب"])
+    with pytest.raises(ValueError, match="unknown match backend"):
+        stemmer.stem_batch(enc, da, backend=backend)
+
+
 # ---------------------------------------------------------------------------
 # Encoding
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """Substrate tests: optimizer, checkpoint-restart, train loop fault
-tolerance, grad compression, data pipeline, serving engine."""
+tolerance, grad compression, data pipeline, int8 KV cache."""
 import os
 import signal
 
@@ -14,7 +14,6 @@ from repro.data import pipeline as data_pipeline
 from repro.dist import compression
 from repro.models import model as model_mod
 from repro.models import params as pm
-from repro.serve.engine import ServeEngine
 from repro.train import checkpoint, loop, optimizer, train_step as ts
 
 
@@ -197,66 +196,6 @@ def test_morph_lm_root_ids_align_with_chunk():
     # boundary word may straddle two chunks)
     for (_, a1), (b0, _) in zip(spans, spans[1:]):
         assert b0 in (a1 - 1, a1)
-
-
-# ---------------------------------------------------------------------------
-# serving engine
-# ---------------------------------------------------------------------------
-def test_engine_continuous_batching(tiny):
-    cfg, params = tiny
-    eng = ServeEngine(cfg, params, max_batch=2, cache_len=64)
-    rng = np.random.default_rng(0)
-    rids = [eng.submit(rng.integers(0, cfg.vocab, 5), max_new=4)
-            for _ in range(4)]  # 4 requests > 2 slots -> queueing
-    eng.run_until_drained()
-    for rid in rids:
-        req = eng.result(rid)
-        assert req is not None and req.done
-        assert len(req.tokens_out) == 4
-        assert all(0 <= t < cfg.vocab for t in req.tokens_out)
-
-
-def test_engine_max_new_exact(tiny):
-    """Regression: a freshly admitted slot used to get a same-tick decode
-    before its doneness check, so max_new=1 returned 2 tokens."""
-    cfg, params = tiny
-    eng = ServeEngine(cfg, params, max_batch=2, cache_len=64)
-    rng = np.random.default_rng(1)
-    rids = {n: eng.submit(rng.integers(0, cfg.vocab, 4), max_new=n)
-            for n in (1, 2, 5)}
-    eng.run_until_drained()
-    for n, rid in rids.items():
-        req = eng.result(rid)
-        assert req is not None and req.done
-        assert len(req.tokens_out) == n, (n, req.tokens_out)
-    # prefill always emits one token, so max_new < 1 is unsatisfiable
-    with pytest.raises(ValueError, match="max_new"):
-        eng.submit(np.asarray([1, 2], np.int32), max_new=0)
-
-
-def test_engine_matches_direct_decode(tiny):
-    """Engine output == straight greedy decode_step loop."""
-    cfg, params = tiny
-    prompt = np.asarray([5, 9, 2, 7], np.int32)
-    eng = ServeEngine(cfg, params, max_batch=2, cache_len=64)
-    rid = eng.submit(prompt, max_new=3)
-    eng.run_until_drained()
-    got = eng.result(rid).tokens_out
-
-    caches = model_mod.init_caches(cfg, 1, cache_len=64)
-    toks = list(prompt)
-    out = []
-    logits = None
-    for i, t in enumerate(toks):
-        logits, caches = model_mod.decode_step(
-            params, cfg, jnp.asarray([[t]], jnp.int32), caches, jnp.int32(i))
-    for j in range(3):
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        logits, caches = model_mod.decode_step(
-            params, cfg, jnp.asarray([[nxt]], jnp.int32), caches,
-            jnp.int32(len(toks) + j))
-    assert got == out
 
 
 # ---------------------------------------------------------------------------
